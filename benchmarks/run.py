@@ -51,6 +51,8 @@ def main() -> None:
     ap.add_argument("--only", default=None,
                     help="comma-separated benchmark keys")
     args = ap.parse_args()
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
     benches = all_benchmarks()
     keys = args.only.split(",") if args.only else list(benches)
     print("name,us_per_call,derived")

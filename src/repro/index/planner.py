@@ -579,7 +579,6 @@ def combine_planned(plans: list[PhysicalPlan],
 def combine_cluster_planned(plans_by_group: list[list[PhysicalPlan]],
                             per_words_by_group: list[list[dict]],
                             is_common_by_group: list[Callable[[str], bool]],
-                            interpret: bool = True,
                             ) -> tuple[list[list[tuple[np.ndarray,
                                                        np.ndarray]]],
                                        np.ndarray]:
@@ -651,7 +650,7 @@ def combine_cluster_planned(plans_by_group: list[list[PhysicalPlan]],
             else:
                 padded[g][q] = programs[g][q]      # zero-layer identity
     progs = pack_cluster_programs(padded, L_max)
-    inter, counts = combine_cluster(bitmaps, progs, interpret=interpret)
+    inter, counts = combine_cluster(bitmaps, progs)
     inter = np.asarray(inter)
     results: list[list[tuple[np.ndarray, np.ndarray]]] = \
         [[(np.empty(0, np.uint64), np.empty(0, np.uint64))] * Q

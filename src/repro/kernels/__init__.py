@@ -8,6 +8,9 @@
 
 Each package ships the Pallas kernel (pl.pallas_call + explicit BlockSpec
 VMEM tiling), a jit'd `ops.py` wrapper, and a pure-jnp `ref.py` oracle.
-The CPU container validates kernels in interpret mode; on real TPUs the
-models flip `kernel_impl="pallas"`.
+The intersect kernels (the served search path) run compiled on a TPU
+and in the Pallas interpreter on the CPU — decided once, from the
+default backend, in `intersect/ops.py`. The model-shell kernels take an
+explicit `interpret=` flag; on real TPUs the models flip
+`kernel_impl="pallas"`.
 """

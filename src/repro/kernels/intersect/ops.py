@@ -1,7 +1,15 @@
-"""Public ops for IoU intersection: bitmap conversion + kernel dispatch."""
+"""Public ops for IoU intersection: bitmap conversion + kernel dispatch.
+
+Whether the Pallas kernels run compiled or in the Pallas interpreter is
+decided here and nowhere else, from the default backend: compiled
+(Mosaic) on a TPU, interpreted on the CPU (where the tests run), and an
+`UnsupportedBackendError` anywhere else. `impl="ref"` selects the
+pure-jnp oracle explicitly; no backend falls back to it.
+"""
 
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -10,6 +18,23 @@ from .kernel import (OP_AND, OP_ANDNOT, OP_OR, combine_batch_pallas,
                      intersect_pallas)
 from .ref import (combine_batch_ref, combine_cluster_ref,
                   intersect_batch_ref, intersect_ref)
+
+
+class UnsupportedBackendError(RuntimeError):
+    """The default JAX backend can neither compile nor interpret the
+    intersect kernels."""
+
+
+def interpret_mode() -> bool:
+    """True when the kernels must run in the Pallas interpreter."""
+    backend = jax.default_backend()
+    if backend == "tpu":
+        return False
+    if backend == "cpu":
+        return True
+    raise UnsupportedBackendError(
+        f"intersect kernels run compiled on 'tpu' or interpreted on 'cpu'; "
+        f"the default backend is {backend!r}")
 
 
 def postings_to_bitmap(postings: list[np.ndarray], n_docs: int) -> np.ndarray:
@@ -47,20 +72,20 @@ def bitmap_to_docs(bitmap: np.ndarray) -> np.ndarray:
     return np.flatnonzero(bits).astype(np.uint32)
 
 
-def intersect(bitmaps, impl: str = "pallas", interpret: bool = True):
+def intersect(bitmaps, impl: str = "pallas"):
     """(L, W) uint32 → (bitmap (W,), count ()). impl: pallas | ref."""
     bitmaps = jnp.asarray(bitmaps, dtype=jnp.uint32)
     if impl == "ref":
         return intersect_ref(bitmaps)
-    return intersect_pallas(bitmaps, interpret=interpret)
+    return intersect_pallas(bitmaps, interpret=interpret_mode())
 
 
-def intersect_batch(bitmaps, impl: str = "pallas", interpret: bool = True):
+def intersect_batch(bitmaps, impl: str = "pallas"):
     """(Q, L, W) uint32 → (bitmaps (Q, W), counts (Q,)). impl: pallas | ref."""
     bitmaps = jnp.asarray(bitmaps, dtype=jnp.uint32)
     if impl == "ref":
         return intersect_batch_ref(bitmaps)
-    return intersect_batch_pallas(bitmaps, interpret=interpret)
+    return intersect_batch_pallas(bitmaps, interpret=interpret_mode())
 
 
 def pack_programs(programs: list[list[tuple[int, int, int]]],
@@ -103,8 +128,7 @@ def pack_cluster_programs(programs: list[list[list[tuple[int, int, int]]]],
     return flat.reshape(len(programs), Q, flat.shape[1], 3)
 
 
-def combine_batch(bitmaps, programs, impl: str = "pallas",
-                  interpret: bool = True):
+def combine_batch(bitmaps, programs, impl: str = "pallas"):
     """Evaluate per-query AND/OR/ANDNOT programs over layered bitsets.
 
     bitmaps: (Q, L, W) uint32; programs: (Q, S, 3) int32 (see
@@ -116,11 +140,10 @@ def combine_batch(bitmaps, programs, impl: str = "pallas",
         return combine_batch_ref(bitmaps, programs)
     return combine_batch_pallas(bitmaps, jnp.asarray(programs,
                                                      dtype=jnp.int32),
-                                interpret=interpret)
+                                interpret=interpret_mode())
 
 
-def combine_cluster(bitmaps, programs, impl: str = "pallas",
-                    interpret: bool = True):
+def combine_cluster(bitmaps, programs, impl: str = "pallas"):
     """Evaluate a whole cluster's combine round in one fused call.
 
     bitmaps: (G, Q, L, W) uint32 — axis 0 is the shard unit; programs:
@@ -134,4 +157,4 @@ def combine_cluster(bitmaps, programs, impl: str = "pallas",
         return combine_cluster_ref(bitmaps, programs)
     return combine_cluster_pallas(bitmaps, jnp.asarray(programs,
                                                        dtype=jnp.int32),
-                                  interpret=interpret)
+                                  interpret=interpret_mode())

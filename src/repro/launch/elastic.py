@@ -11,6 +11,7 @@ separation-of-compute-and-storage way) onto the new mesh's shardings.
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
 
 from ..models.common import rules_for
 from ..training.checkpoint import CheckpointManager
@@ -23,7 +24,8 @@ def choose_mesh(n_devices: int | None = None, prefer_model: int = 16):
     while model > 1 and (n % model or model > n):
         model //= 2
     data = n // model
-    return jax.make_mesh((data, model), ("data", "model"))
+    return jax.make_mesh((data, model), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2)
 
 
 def reshard_restore(ckpt: CheckpointManager, model, mesh, step=None,

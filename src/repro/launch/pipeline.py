@@ -12,27 +12,15 @@ scanned layer block).
 
 from __future__ import annotations
 
-from functools import partial
-
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding
 from jax.sharding import PartitionSpec as P
 
-# jax < 0.6 compat: shard_map lived under jax.experimental and had no
-# varying-ness type system (no jax.lax.pcast) — there, replication
-# checking is disabled instead and the pcasts are identities.
-_HAS_PCAST = hasattr(jax.lax, "pcast")
-if hasattr(jax, "shard_map"):
-    _shard_map = jax.shard_map
-else:
-    from jax.experimental.shard_map import shard_map as _shard_map
-    _shard_map = partial(_shard_map, check_rep=False)
-
 
 def _pipe_varying(x):
-    """Mark an initial carry device-varying over 'pipe' (newer jax)."""
-    return jax.lax.pcast(x, ("pipe",), to="varying") if _HAS_PCAST else x
+    """Mark an initial carry device-varying over 'pipe'."""
+    return jax.lax.pcast(x, ("pipe",), to="varying")
 
 
 def _stage_fn(w, x):
@@ -96,10 +84,11 @@ def pipelined_mlp(mesh: Mesh, ws: jax.Array, x: jax.Array,
 
     spec_w = P("pipe", None, None)
     spec_x = P()          # replicated microbatch feed
-    out = jax.jit(_shard_map(
+    out = jax.jit(jax.shard_map(
         stage_program, mesh=mesh, in_specs=(spec_w, spec_x),
         out_specs=P("pipe", None, None)))(ws, xs)
     # out: (n_stages*n_micro, micro, d) — every stage wrote its copy; only
     # the LAST stage's block holds the real results.
-    out = out.reshape(n_stages, n_micro, micro, d)[-1]
+    out = out.reshape(n_stages, n_micro, micro, d).at[-1].get(
+        out_sharding=NamedSharding(mesh, P()))
     return out.reshape(batch, d)

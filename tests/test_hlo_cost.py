@@ -83,14 +83,16 @@ def test_sharded_collectives_counted_per_iteration():
         from repro.launch.hlo_cost import analyze_hlo
         D, B, L = 128, 64, 4
         mesh = jax.make_mesh((4, 2), ("data", "model"))
+        x_sh = NamedSharding(mesh, P("data", None))
         def f(w, x):
-            def body(c, wl): return jnp.tanh(c @ wl), None
+            # the carry keeps x's sharding across iterations
+            def body(c, wl):
+                return jax.sharding.reshard(jnp.tanh(c @ wl), x_sh), None
             y, _ = jax.lax.scan(body, x, w)
             return y.sum()
         ws = jax.ShapeDtypeStruct((L, D, D), jnp.float32,
             sharding=NamedSharding(mesh, P(None, "data", "model")))
-        xs = jax.ShapeDtypeStruct((B, D), jnp.float32,
-            sharding=NamedSharding(mesh, P("data", None)))
+        xs = jax.ShapeDtypeStruct((B, D), jnp.float32, sharding=x_sh)
         c = jax.jit(f).lower(ws, xs).compile()
         s = analyze_hlo(c.as_text())
         per_dev = 2.0*L*B*D*D/8
