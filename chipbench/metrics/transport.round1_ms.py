@@ -1,0 +1,10 @@
+"""Mean wall time of a batch's round-1 (superpost) fetch, as the
+program's `QueryStats.lookup.elapsed_s` reports it; one sample a batch."""
+
+import numpy as np
+
+
+def read(rec):
+    if not rec.spans:
+        return None
+    return float(np.mean([s.round1_s for s in rec.spans])) * 1e3
