@@ -11,9 +11,14 @@ it":
     maintains a global acquisition-order graph and fails fast on
     lock-order inversions (armed via ``REPRO_LOCK_CHECK=1``) instead of
     letting a deadlock hang the soak test.
+  * `analysis.spans` — the span recorder of the served path: named,
+    nested host intervals with their batch and attributes, on the
+    profiler's clock (each opens a `jax.profiler.TraceAnnotation`),
+    and a `jit.compile` record per backend compile.
 
-The package is dependency-free (stdlib only) so every layer — storage,
-index, serving — can import `analysis.locks` without cycles.
+The package imports nothing outside the standard library at import time
+(`spans` loads JAX only once it records), so every layer — storage,
+index, kernels, serving — can import it without cycles.
 """
 
 from .locks import (LockOrderViolation, OrderedLock, arm, armed,

@@ -45,6 +45,7 @@ from dataclasses import asdict, dataclass, field, replace
 
 import msgpack
 
+from ..analysis import spans
 from ..compat import UngracedSweepError, deprecated_call
 from ..data.corpus import Corpus, DocRef
 from ..storage.blobstore import RangeRequest
@@ -224,7 +225,9 @@ class MultiSegmentSearcher:
                     top_k: int | None = None, hedge: bool = False,
                     impl: str = "sorted",
                     batch_stats=None) -> list[QueryResult]:
-        jobs = plan_batch(queries, units=tuple(self.units), top_k=top_k)
+        with spans.span("searcher.plan") as s:
+            jobs = plan_batch(queries, units=tuple(self.units), top_k=top_k)
+            s.set(jobs=len(jobs))
         return execute_jobs(self.units, jobs, self._fetcher,
                             hedge=hedge, impl=impl,
                             batch_stats=batch_stats)

@@ -50,6 +50,7 @@ from typing import Callable
 
 import numpy as np
 
+from ..analysis import spans
 from ..core.hashing import word_fingerprint
 from ..core.sketch import intersect_sorted
 from ..data.tokenizer import parse_words
@@ -517,51 +518,61 @@ def combine_planned(plans: list[PhysicalPlan],
     evaluates every compiled program in ONE batched Pallas
     `combine_batch` call (AND/OR/ANDNOT fused per document tile).
     """
-    compiled = [_compile_steps(p, pw, is_common)
-                for p, pw in zip(plans, per_words)]
     if impl != "bitmap":
-        return [_eval_steps(leaves, steps) for leaves, steps in compiled]
+        return [_eval_steps(*_compile_steps(p, pw, is_common))
+                for p, pw in zip(plans, per_words)]
 
-    from ..kernels.intersect import combine_batch, pack_programs
+    from ..kernels.intersect import (combine_batch, pack_programs,
+                                     postings_to_bitmap_batch)
 
-    universes: list[np.ndarray | None] = []
-    rows: list[list[np.ndarray]] = []
-    programs: list[list[tuple[int, int, int]]] = []
-    out: list[tuple[np.ndarray, np.ndarray] | None] = [None] * len(plans)
-    for j, (leaves, steps) in enumerate(compiled):
-        keys_list = [k for k, _l in leaves]
-        uni = np.unique(np.concatenate(keys_list)) if keys_list else \
-            np.empty(0, np.uint64)
-        if not len(uni):
-            universes.append(None)
-            out[j] = (np.empty(0, np.uint64), np.empty(0, np.uint64))
-            continue
-        universes.append(uni)
-        rows.append([np.searchsorted(uni, k).astype(np.uint32)
-                     for k in keys_list])
-        programs.append(steps)
-    if rows:
-        from ..kernels.intersect import postings_to_bitmap_batch
-        n_bits = max(len(u) for u in universes if u is not None)
-        L_max = max(len(r) for r in rows)
-        # ragged padding: unused layers are all-zero (never referenced —
-        # programs only touch their own leaves)
-        W = (n_bits + 31) // 32
-        bitmaps = np.zeros((len(rows), L_max, W), dtype=np.uint32)
-        for q, posts in enumerate(rows):
-            bitmaps[q, :len(posts)] = postings_to_bitmap_batch(
-                [posts], n_bits)[0, :len(posts)]
-        # re-point step slots at the padded layer count
-        padded = []
-        for posts, steps in zip(rows, programs):
-            shift = L_max - len(posts)
-            padded.append([(op,
-                            a + shift if a >= len(posts) else a,
-                            b + shift if b >= len(posts) else b)
-                           for op, a, b in steps])
-        progs = pack_programs(padded, L_max)
+    with spans.span("combine.build") as s:
+        compiled = [_compile_steps(p, pw, is_common)
+                    for p, pw in zip(plans, per_words)]
+        universes: list[np.ndarray | None] = []
+        rows: list[list[np.ndarray]] = []
+        programs: list[list[tuple[int, int, int]]] = []
+        out: list[tuple[np.ndarray, np.ndarray] | None] = [None] * len(plans)
+        for j, (leaves, steps) in enumerate(compiled):
+            keys_list = [k for k, _l in leaves]
+            uni = np.unique(np.concatenate(keys_list)) if keys_list else \
+                np.empty(0, np.uint64)
+            if not len(uni):
+                universes.append(None)
+                out[j] = (np.empty(0, np.uint64), np.empty(0, np.uint64))
+                continue
+            universes.append(uni)
+            rows.append([np.searchsorted(uni, k).astype(np.uint32)
+                         for k in keys_list])
+            programs.append(steps)
+        if rows:
+            n_bits = max(len(u) for u in universes if u is not None)
+            L_max = max(len(r) for r in rows)
+            # ragged padding: unused layers are all-zero (never
+            # referenced — programs only touch their own leaves)
+            W = (n_bits + 31) // 32
+            bitmaps = np.zeros((len(rows), L_max, W), dtype=np.uint32)
+            for q, posts in enumerate(rows):
+                bitmaps[q, :len(posts)] = postings_to_bitmap_batch(
+                    [posts], n_bits)[0, :len(posts)]
+            # re-point step slots at the padded layer count
+            padded = []
+            for posts, steps in zip(rows, programs):
+                shift = L_max - len(posts)
+                padded.append([(op,
+                                a + shift if a >= len(posts) else a,
+                                b + shift if b >= len(posts) else b)
+                               for op, a, b in steps])
+            progs = pack_programs(padded, L_max)
+            shape = dict(zip("QLW", bitmaps.shape))
+            s.set(kernel="combine_batch", **shape)
+    if not rows:
+        return out  # type: ignore[return-value]
+
+    with spans.span("combine.call") as s:
+        s.set(kernel="combine_batch", **shape)
         inter, _counts = combine_batch(bitmaps, progs)
         inter = np.asarray(inter)
+    with spans.span("combine.unpack"):
         row_i = 0
         for j, (leaves, _steps) in enumerate(compiled):
             if universes[j] is None:

@@ -52,6 +52,7 @@ from dataclasses import dataclass, field, replace
 
 import numpy as np
 
+from ..analysis import spans
 from ..compat import deprecated_call
 from ..core.hashing import HashFamily, word_fingerprint
 from ..core.sketch import intersect_sorted
@@ -280,14 +281,6 @@ class Searcher:
         self.ngram_n: int | None = \
             None if raw_ngrams is None else int(raw_ngrams)
 
-    def bind_telemetry(self, telemetry, prefix: str = "fetch",
-                       ) -> "Searcher":
-        """Export this reader's fetch rounds (latency, bytes) and its
-        transport's traffic into a metrics registry. Returns self."""
-        self._fetcher.bind_telemetry(telemetry, prefix)
-        self.transport.bind_telemetry(telemetry, f"{prefix}.transport")
-        return self
-
     # fetch knobs live in ONE place — the _Fetcher every round goes
     # through — so post-construction mutation keeps taking effect
     @property
@@ -417,7 +410,9 @@ class Searcher:
         With `impl="bitmap"`, candidate combines run through the batched
         Pallas kernels (`kernels/intersect`).
         """
-        jobs = plan_batch(queries, units=(self,), top_k=top_k)
+        with spans.span("searcher.plan") as s:
+            jobs = plan_batch(queries, units=(self,), top_k=top_k)
+            s.set(jobs=len(jobs))
         return self._execute_jobs(jobs, hedge=hedge, impl=impl,
                                   batch_stats=batch_stats)
 
@@ -483,80 +478,88 @@ def lookup_units(units: list[Searcher], queries: list[Query | str],
     qs = [Term(q) if isinstance(q, str) else q for q in queries]
     word_lists = [query_words(q) for q in qs]
     stats = QueryStats()
-    plans = [u._plan_words(word_lists) for u in units]
-    requests: list[RangeRequest] = []
-    hedgeable: set[int] = set()
-    bases: list[int] = []
-    local: dict[int, bytes] = {}
-    for unit, plan in zip(units, plans):
-        base = len(requests)
-        bases.append(base)
-        requests.extend(plan.requests)
-        resolve = getattr(unit, "resolve_local", None)
-        if resolve is not None:
-            # memory-resident unit (index/nrt.py): its superposts never
-            # touch the wire — answered synchronously from process memory,
-            # excluded from the shared fetch round and from hedging
-            for i, req in enumerate(plan.requests):
-                local[base + i] = resolve(req)
+    with spans.span("searcher.round1") as s:
+        plans = [u._plan_words(word_lists) for u in units]
+        requests: list[RangeRequest] = []
+        hedgeable: set[int] = set()
+        bases: list[int] = []
+        local: dict[int, bytes] = {}
+        for unit, plan in zip(units, plans):
+            base = len(requests)
+            bases.append(base)
+            requests.extend(plan.requests)
+            resolve = getattr(unit, "resolve_local", None)
+            if resolve is not None:
+                # memory-resident unit (index/nrt.py): its superposts
+                # never touch the wire — answered synchronously from
+                # process memory, excluded from the shared fetch round
+                # and from hedging
+                for i, req in enumerate(plan.requests):
+                    local[base + i] = resolve(req)
+            else:
+                hedgeable.update(i + base for i in plan.hedgeable)
+        if local:
+            net = [i for i in range(len(requests)) if i not in local]
+            net_payloads, fstats = fetcher.fetch_ranges(
+                [requests[i] for i in net], hedge=hedge,
+                hedgeable={k for k, i in enumerate(net) if i in hedgeable},
+                use_cache=True)
+            payloads = [None] * len(requests)
+            for k, i in enumerate(net):
+                payloads[i] = net_payloads[k]
+            for i, p in local.items():
+                payloads[i] = p
         else:
-            hedgeable.update(i + base for i in plan.hedgeable)
-    if local:
-        net = [i for i in range(len(requests)) if i not in local]
-        net_payloads, fstats = fetcher.fetch_ranges(
-            [requests[i] for i in net], hedge=hedge,
-            hedgeable={k for k, i in enumerate(net) if i in hedgeable},
-            use_cache=True)
-        payloads = [None] * len(requests)
-        for k, i in enumerate(net):
-            payloads[i] = net_payloads[k]
-        for i, p in local.items():
-            payloads[i] = p
-    else:
-        # no memory units: the exact pre-NRT single-batch path
-        payloads, fstats = fetcher.fetch_ranges(
-            requests, hedge=hedge, hedgeable=hedgeable, use_cache=True)
-    stats.lookup = fstats
-    stats.rounds += 1
+            # no memory units: the exact pre-NRT single-batch path
+            payloads, fstats = fetcher.fetch_ranges(
+                requests, hedge=hedge, hedgeable=hedgeable, use_cache=True)
+        stats.lookup = fstats
+        stats.rounds += 1
 
-    # hedging must keep >= 1 layer per word per unit: re-fetch (in ONE
-    # batch) the first layer of any word whose every request was abandoned
-    missing: list[int] = []
-    for plan, base in zip(plans, bases):
-        missing.extend(base + plan.word_reqs[w][0] for w in plan.words
-                       if all(payloads[base + i] is None
-                              for i in plan.word_reqs[w]))
-    if missing:
-        fb, extra = fetcher.transport.fetch_batch(
-            [requests[i] for i in missing])
-        stats.lookup.add(extra)
-        for i, p in zip(missing, fb):
-            payloads[i] = p
+        # hedging must keep >= 1 layer per word per unit: re-fetch (in
+        # ONE batch) the first layer of any word whose every request was
+        # abandoned
+        missing: list[int] = []
+        for plan, base in zip(plans, bases):
+            missing.extend(base + plan.word_reqs[w][0] for w in plan.words
+                           if all(payloads[base + i] is None
+                                  for i in plan.word_reqs[w]))
+        if missing:
+            fb, extra = fetcher.transport.fetch_batch(
+                [requests[i] for i in missing])
+            stats.lookup.add(extra)
+            for i, p in zip(missing, fb):
+                payloads[i] = p
+        s.set(requests=len(requests))
 
     # --- phase: decode (each unique superpost exactly once) -------------
-    outs_per_unit: list[list[dict[str, tuple[np.ndarray, np.ndarray]]]] = []
-    n_candidates = 0
-    for plan, base in zip(plans, bases):
-        decoded: dict[int, tuple[np.ndarray, np.ndarray]] = {}
-        word_out: dict[str, tuple[np.ndarray, np.ndarray]] = {}
-        for w in plan.words:
-            posts = []
-            for i in plan.word_reqs[w]:
-                if payloads[base + i] is None:   # hedged-away straggler
-                    continue
-                if i not in decoded:
-                    decoded[i] = codec.decode_superpost(payloads[base + i])
-                posts.append(decoded[i])
-            keys = intersect_sorted([k for k, _len in posts])
-            # recover lengths from whichever layer, via searchsorted
-            k0, l0 = posts[0]
-            lengths = l0[np.searchsorted(k0, keys)]
-            word_out[w] = (keys, lengths)
-        outs = [{w: word_out[w] for w in wl} for wl in word_lists]
-        n_candidates += int(
-            sum(len(k) for d in outs for k, _ in d.values()))
-        outs_per_unit.append(outs)
-    stats.n_candidates = n_candidates
+    with spans.span("searcher.decode") as s:
+        outs_per_unit: list[list[dict[str, tuple[np.ndarray,
+                                                 np.ndarray]]]] = []
+        n_candidates = 0
+        for plan, base in zip(plans, bases):
+            decoded: dict[int, tuple[np.ndarray, np.ndarray]] = {}
+            word_out: dict[str, tuple[np.ndarray, np.ndarray]] = {}
+            for w in plan.words:
+                posts = []
+                for i in plan.word_reqs[w]:
+                    if payloads[base + i] is None:   # hedged-away straggler
+                        continue
+                    if i not in decoded:
+                        decoded[i] = codec.decode_superpost(
+                            payloads[base + i])
+                    posts.append(decoded[i])
+                keys = intersect_sorted([k for k, _len in posts])
+                # recover lengths from whichever layer, via searchsorted
+                k0, l0 = posts[0]
+                lengths = l0[np.searchsorted(k0, keys)]
+                word_out[w] = (keys, lengths)
+            outs = [{w: word_out[w] for w in wl} for wl in word_lists]
+            n_candidates += int(
+                sum(len(k) for d in outs for k, _ in d.values()))
+            outs_per_unit.append(outs)
+        stats.n_candidates = n_candidates
+        s.set(candidates=n_candidates)
     return outs_per_unit, stats
 
 
@@ -582,42 +585,46 @@ def execute_jobs(units: list[Searcher], jobs: list[_Job], fetcher: _Fetcher,
                 for _ in jobs]
 
     # --- top-K sampling (§IV-D, Eq. 6) per (unit, job) ------------------
-    sampled: list[list[tuple[np.ndarray, np.ndarray]]] = \
-        [[None] * len(jobs) for _ in units]    # type: ignore[list-item]
-    orders: list[list[np.ndarray]] = \
-        [[None] * len(jobs) for _ in units]    # type: ignore[list-item]
-    wants: list[int] = [0] * len(jobs)
-    for j, job in enumerate(jobs):
-        total = sum(len(combined[u][j][0]) for u in range(n_units))
-        stats_of[j].n_candidates = total
-        if batch_stats is not None:
-            batch_stats.n_candidates += total
-        want = total
-        if job.top_k is not None and total:
-            want = job.top_k
-        wants[j] = want
-        for u, unit in enumerate(units):
-            keys, lengths = combined[u][j]
-            order = np.arange(len(keys))
-            if job.top_k is not None and len(keys):
-                rk = sample_size(len(keys), job.top_k, unit.F0, job.delta)
-                order = topk_order(keys)
-                sampled[u][j] = (keys[order[:rk]], lengths[order[:rk]])
-            else:
-                sampled[u][j] = (keys, lengths)
-            orders[u][j] = order
-        if not job.fetch_documents:
-            refs, _texts = _merge_results(
-                [units[u]._refs(*combined[u][j]) for u in range(n_units)],
-                None, already_merged=n_units == 1,
-                sort=job.top_k is None)
-            results[j] = QueryResult(refs=refs, texts=[],
-                                     stats=stats_of[j])
+    with spans.span("searcher.sample"):
+        sampled: list[list[tuple[np.ndarray, np.ndarray]]] = \
+            [[None] * len(jobs) for _ in units]    # type: ignore[list-item]
+        orders: list[list[np.ndarray]] = \
+            [[None] * len(jobs) for _ in units]    # type: ignore[list-item]
+        wants: list[int] = [0] * len(jobs)
+        for j, job in enumerate(jobs):
+            total = sum(len(combined[u][j][0]) for u in range(n_units))
+            stats_of[j].n_candidates = total
+            if batch_stats is not None:
+                batch_stats.n_candidates += total
+            want = total
+            if job.top_k is not None and total:
+                want = job.top_k
+            wants[j] = want
+            for u, unit in enumerate(units):
+                keys, lengths = combined[u][j]
+                order = np.arange(len(keys))
+                if job.top_k is not None and len(keys):
+                    rk = sample_size(len(keys), job.top_k, unit.F0,
+                                     job.delta)
+                    order = topk_order(keys)
+                    sampled[u][j] = (keys[order[:rk]],
+                                     lengths[order[:rk]])
+                else:
+                    sampled[u][j] = (keys, lengths)
+                orders[u][j] = order
+            if not job.fetch_documents:
+                refs, _texts = _merge_results(
+                    [units[u]._refs(*combined[u][j])
+                     for u in range(n_units)],
+                    None, already_merged=n_units == 1,
+                    sort=job.top_k is None)
+                results[j] = QueryResult(refs=refs, texts=[],
+                                         stats=stats_of[j])
+        live = [j for j in range(len(jobs)) if results[j] is None]
+        unit_job_refs = [{j: units[u]._refs(*sampled[u][j]) for j in live}
+                         for u in range(n_units)]
 
     # --- round 2: ONE deduplicated+coalesced batch for all units+jobs ---
-    live = [j for j in range(len(jobs)) if results[j] is None]
-    unit_job_refs = [{j: units[u]._refs(*sampled[u][j]) for j in live}
-                     for u in range(n_units)]
     batch_docs = batch_stats.docs if batch_stats is not None else None
     texts_of, refs_of = _fetch_and_filter_units(
         units, jobs, unit_job_refs, stats_of, fetcher,
@@ -626,47 +633,53 @@ def execute_jobs(units: list[Searcher], jobs: list[_Job], fetcher: _Fetcher,
     # --- Eq. 6 failure (prob < delta) or tiny candidate set: fall back
     # to fetching the remainder — again ONE batch for every unit of every
     # job that came up short.
-    fallback: list[dict[int, list[DocRef]]] = [{} for _ in units]
-    if any(jobs[j].top_k is not None for j in live):
-        for j in live:
-            if jobs[j].top_k is None:
-                continue
-            # count unique doc identities — a doc accepted by several
-            # units (duplicate append) merges to ONE result, so a per-
-            # unit sum could skip a fallback the deduped set still needs
-            accepted = len({(r.blob, r.offset, r.length)
-                            for u in range(n_units)
-                            for r in refs_of[u][j]})
-            if accepted >= wants[j]:
-                continue
+    with spans.span("searcher.fallback") as s:
+        fallback: list[dict[int, list[DocRef]]] = [{} for _ in units]
+        if any(jobs[j].top_k is not None for j in live):
+            for j in live:
+                if jobs[j].top_k is None:
+                    continue
+                # count unique doc identities — a doc accepted by
+                # several units (duplicate append) merges to ONE result,
+                # so a per-unit sum could skip a fallback the deduped set
+                # still needs
+                accepted = len({(r.blob, r.offset, r.length)
+                                for u in range(n_units)
+                                for r in refs_of[u][j]})
+                if accepted >= wants[j]:
+                    continue
+                for u in range(n_units):
+                    keys, lengths = combined[u][j]
+                    n_sampled = len(sampled[u][j][0])
+                    if len(keys) > n_sampled:
+                        rest = orders[u][j][n_sampled:]
+                        fallback[u][j] = units[u]._refs(keys[rest],
+                                                        lengths[rest])
+        if any(fallback):
+            t2, r2 = _fetch_and_filter_units(units, jobs, fallback,
+                                             stats_of, fetcher,
+                                             batch_docs=batch_docs)
             for u in range(n_units):
-                keys, lengths = combined[u][j]
-                n_sampled = len(sampled[u][j][0])
-                if len(keys) > n_sampled:
-                    rest = orders[u][j][n_sampled:]
-                    fallback[u][j] = units[u]._refs(keys[rest],
-                                                    lengths[rest])
-    if any(fallback):
-        t2, r2 = _fetch_and_filter_units(units, jobs, fallback, stats_of,
-                                         fetcher, batch_docs=batch_docs)
-        for u in range(n_units):
-            for j in fallback[u]:
-                texts_of[u][j] += t2[u][j]
-                refs_of[u][j] += r2[u][j]
+                for j in fallback[u]:
+                    texts_of[u][j] += t2[u][j]
+                    refs_of[u][j] += r2[u][j]
+        s.set(jobs=len({j for f in fallback for j in f}),
+              docs=sum(len(r) for f in fallback for r in f.values()))
 
     # --- union per job across units (dedupe doc identity; non-top-K
     # results restored to the monolithic (blob, offset) order) -----------
-    for j in live:
-        refs, texts = _merge_results(
-            [refs_of[u][j] for u in range(n_units)],
-            [texts_of[u][j] for u in range(n_units)],
-            already_merged=n_units == 1,
-            sort=jobs[j].top_k is None)
-        if jobs[j].top_k is not None:
-            texts, refs = texts[:wants[j]], refs[:wants[j]]
-        stats_of[j].n_results = len(texts)
-        results[j] = QueryResult(refs=refs, texts=texts,
-                                 stats=stats_of[j])
+    with spans.span("searcher.merge"):
+        for j in live:
+            refs, texts = _merge_results(
+                [refs_of[u][j] for u in range(n_units)],
+                [texts_of[u][j] for u in range(n_units)],
+                already_merged=n_units == 1,
+                sort=jobs[j].top_k is None)
+            if jobs[j].top_k is not None:
+                texts, refs = texts[:wants[j]], refs[:wants[j]]
+            stats_of[j].n_results = len(texts)
+            results[j] = QueryResult(refs=refs, texts=texts,
+                                     stats=stats_of[j])
     return results  # type: ignore[return-value]
 
 
@@ -715,65 +728,74 @@ def _fetch_and_filter_units(units: list[Searcher], jobs: list[_Job],
     """Round 2 for many jobs across units: documents wanted by several
     queries (or several units) are fetched once; ranges are coalesced;
     false positives filtered per job by its own acceptance predicate."""
-    uniq: dict[tuple[str, int, int], int] = {}
-    requests: list[RangeRequest] = []
-    for refs_by_job in unit_job_refs:
-        for j in sorted(refs_by_job):
-            for r in refs_by_job[j]:
-                key = (r.blob, r.offset, r.length)
-                if key not in uniq:
-                    uniq[key] = len(requests)
-                    requests.append(RangeRequest(r.blob, r.offset, r.length))
-    texts_of = [{j: [] for j in refs_by_job}
-                for refs_by_job in unit_job_refs]
-    refs_of = [{j: [] for j in refs_by_job}
-               for refs_by_job in unit_job_refs]
-    if not requests:
-        return texts_of, refs_of
-    payloads, fstats = fetcher.fetch_ranges(requests)
-    if batch_docs is not None:
-        batch_docs.add(fstats)
-    # a job's doc round is accounted once, no matter how many units fed it
-    rounds_jobs = sorted({j for refs_by_job in unit_job_refs
-                          for j, refs in refs_by_job.items() if refs})
-    for j in rounds_jobs:
-        stats_of[j].docs.add(fstats)
-        stats_of[j].rounds += 1
-    # decode-once: a document wanted by several queries is utf-8
-    # decoded (and tokenized, for word/content filters) a single time —
-    # one DocContent serves classic word filters and planner verifiers
-    texts_u: list[str | None] = [None] * len(requests)
-    content_u: list[DocContent | None] = [None] * len(requests)
-    # a doc indexed by several units is ONE false positive for a job, as
-    # it would be in a monolithic rebuild — dedupe rejections by identity
-    rejected: dict[int, set[int]] = {}
-    for u, refs_by_job in enumerate(unit_job_refs):
-        for j, refs in refs_by_job.items():
-            if not refs:         # done after round 1 — no doc round for it
-                continue
-            job = jobs[j]
-            for ref in refs:
-                i = uniq[(ref.blob, ref.offset, ref.length)]
-                if texts_u[i] is None:
-                    payload = payloads[i]
-                    assert payload is not None
-                    texts_u[i] = payload.decode("utf-8")
-                text = texts_u[i]
-                if job.accept_text is not None:
-                    ok = job.accept_text(text)
-                else:
-                    if content_u[i] is None:
-                        content_u[i] = DocContent(text)
-                    if job.accept_doc is not None:
-                        ok = job.accept_doc(content_u[i])
+    with spans.span("searcher.round2") as s:
+        uniq: dict[tuple[str, int, int], int] = {}
+        requests: list[RangeRequest] = []
+        for refs_by_job in unit_job_refs:
+            for j in sorted(refs_by_job):
+                for r in refs_by_job[j]:
+                    key = (r.blob, r.offset, r.length)
+                    if key not in uniq:
+                        uniq[key] = len(requests)
+                        requests.append(
+                            RangeRequest(r.blob, r.offset, r.length))
+        texts_of = [{j: [] for j in refs_by_job}
+                    for refs_by_job in unit_job_refs]
+        refs_of = [{j: [] for j in refs_by_job}
+                   for refs_by_job in unit_job_refs]
+        s.set(requests=len(requests))
+        if not requests:
+            return texts_of, refs_of
+        payloads, fstats = fetcher.fetch_ranges(requests)
+        if batch_docs is not None:
+            batch_docs.add(fstats)
+        # a job's doc round is accounted once, no matter how many units fed it
+        rounds_jobs = sorted({j for refs_by_job in unit_job_refs
+                              for j, refs in refs_by_job.items() if refs})
+        for j in rounds_jobs:
+            stats_of[j].docs.add(fstats)
+            stats_of[j].rounds += 1
+
+    with spans.span("searcher.verify") as s:
+        # decode-once: a document wanted by several queries is utf-8
+        # decoded (and tokenized, for word/content filters) a single time —
+        # one DocContent serves classic word filters and planner verifiers
+        texts_u: list[str | None] = [None] * len(requests)
+        content_u: list[DocContent | None] = [None] * len(requests)
+        # a doc indexed by several units is ONE false positive for a job, as
+        # it would be in a monolithic rebuild — dedupe rejections by identity
+        rejected: dict[int, set[int]] = {}
+        for u, refs_by_job in enumerate(unit_job_refs):
+            for j, refs in refs_by_job.items():
+                if not refs:     # done after round 1 — no doc round for it
+                    continue
+                job = jobs[j]
+                for ref in refs:
+                    i = uniq[(ref.blob, ref.offset, ref.length)]
+                    if texts_u[i] is None:
+                        payload = payloads[i]
+                        assert payload is not None
+                        texts_u[i] = payload.decode("utf-8")
+                    text = texts_u[i]
+                    if job.accept_text is not None:
+                        ok = job.accept_text(text)
                     else:
-                        ok = job.accept_words(content_u[i].words)
-                if ok:
-                    texts_of[u][j].append(text)
-                    refs_of[u][j].append(ref)
-                elif i not in rejected.setdefault(j, set()):
-                    rejected[j].add(i)
-                    stats_of[j].n_false_positives += 1
+                        if content_u[i] is None:
+                            content_u[i] = DocContent(text)
+                        if job.accept_doc is not None:
+                            ok = job.accept_doc(content_u[i])
+                        else:
+                            ok = job.accept_words(content_u[i].words)
+                    if ok:
+                        texts_of[u][j].append(text)
+                        refs_of[u][j].append(ref)
+                    elif i not in rejected.setdefault(j, set()):
+                        rejected[j].add(i)
+                        stats_of[j].n_false_positives += 1
+        s.set(docs=sum(len(refs) for refs_by_job in unit_job_refs
+                       for refs in refs_by_job.values()),
+              accepted=sum(len(refs) for refs_by_job in refs_of
+                           for refs in refs_by_job.values()))
     return texts_of, refs_of
 
 
@@ -858,37 +880,45 @@ def _bitmap_and_batch(parts_list: list[list[tuple[np.ndarray, np.ndarray]]],
     """
     from ..kernels.intersect import intersect_batch, postings_to_bitmap_batch
 
-    universes: list[np.ndarray | None] = []
-    rows: list[list[np.ndarray]] = []
-    for parts in parts_list:
-        keys_list = [k for k, _l in parts]
-        if any(len(k) == 0 for k in keys_list):
-            universes.append(None)      # empty AND — no kernel work
-            continue
-        uni = np.unique(np.concatenate(keys_list))
-        universes.append(uni)
-        rows.append([np.searchsorted(uni, k).astype(np.uint32)
-                     for k in keys_list])
+    with spans.span("combine.build") as s:
+        universes: list[np.ndarray | None] = []
+        rows: list[list[np.ndarray]] = []
+        for parts in parts_list:
+            keys_list = [k for k, _l in parts]
+            if any(len(k) == 0 for k in keys_list):
+                universes.append(None)      # empty AND — no kernel work
+                continue
+            uni = np.unique(np.concatenate(keys_list))
+            universes.append(uni)
+            rows.append([np.searchsorted(uni, k).astype(np.uint32)
+                         for k in keys_list])
+        if rows:
+            n_bits = max(len(u) for u in universes if u is not None)
+            bitmaps = postings_to_bitmap_batch(rows, n_bits)
+            shape = dict(zip("QLW", bitmaps.shape))
+            s.set(kernel="intersect_batch", **shape)
 
-    out: list[tuple[np.ndarray, np.ndarray]] = []
     if rows:
-        n_bits = max(len(u) for u in universes if u is not None)
-        bitmaps = postings_to_bitmap_batch(rows, n_bits)
-        inter, _counts = intersect_batch(bitmaps)
-        inter = np.asarray(inter)
-    row_i = 0
-    for parts, uni in zip(parts_list, universes):
-        if uni is None:
-            out.append((np.empty(0, dtype=np.uint64),
-                        np.empty(0, dtype=np.uint64)))
-            continue
-        bits = np.unpackbits(inter[row_i].view(np.uint8), bitorder="little")
-        sel = np.flatnonzero(bits[:len(uni)])
-        row_i += 1
-        keys = uni[sel]
-        k0, l0 = parts[0]
-        lengths = l0[np.searchsorted(k0, keys)]
-        out.append((keys, lengths))
+        with spans.span("combine.call") as s:
+            s.set(kernel="intersect_batch", **shape)
+            inter, _counts = intersect_batch(bitmaps)
+            inter = np.asarray(inter)
+    with spans.span("combine.unpack"):
+        out: list[tuple[np.ndarray, np.ndarray]] = []
+        row_i = 0
+        for parts, uni in zip(parts_list, universes):
+            if uni is None:
+                out.append((np.empty(0, dtype=np.uint64),
+                            np.empty(0, dtype=np.uint64)))
+                continue
+            bits = np.unpackbits(inter[row_i].view(np.uint8),
+                                 bitorder="little")
+            sel = np.flatnonzero(bits[:len(uni)])
+            row_i += 1
+            keys = uni[sel]
+            k0, l0 = parts[0]
+            lengths = l0[np.searchsorted(k0, keys)]
+            out.append((keys, lengths))
     return out
 
 

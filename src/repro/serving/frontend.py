@@ -35,6 +35,7 @@ from collections import deque
 from concurrent.futures import Future
 from dataclasses import dataclass, field
 
+from ..analysis import spans
 from ..analysis.locks import ordered_condition
 from ..index.query import Query
 
@@ -281,6 +282,12 @@ class Frontend:
         self._maybe_refresh()
         if not batch:
             return 0
+        with spans.span("frontend.serve", starts_batch=True) as s:
+            s.set(n=len(batch))
+            self._serve_batch(batch)
+        return len(batch)
+
+    def _serve_batch(self, batch: list[_Pending]) -> None:
         now = self.clock()
         live: list[_Pending] = []
         expired: list[_Pending] = []
@@ -314,7 +321,7 @@ class Frontend:
             for w in waits:
                 self._h_wait.observe(w)
         if not live:
-            return len(batch)
+            return
         # one shared plan/fetch round per distinct top_k (almost always
         # one group — mixed-k batches split but still amortize within k)
         by_k: dict[object, list[_Pending]] = {}
@@ -343,7 +350,6 @@ class Frontend:
             self.controller.on_batch(service_s, len(live))
         if self.shedder is not None:
             self.shedder.on_batch(service_s, len(live))
-        return len(batch)
 
     def _execute(self, queries: list, top_k) -> list:
         if hasattr(self.backend, "search_batch"):
@@ -362,7 +368,7 @@ class Frontend:
     def _loop(self) -> None:
         cfg = self.config
         while True:
-            with self._cond:
+            with spans.span("frontend.wait"), self._cond:
                 while not self._queue and not self._closed:
                     self._cond.wait()
                 if self._closed and not self._queue:

@@ -31,6 +31,7 @@ from dataclasses import dataclass, field
 
 import numpy as np
 
+from ..analysis import spans
 from ..compat import deprecated_call
 from ..index.lifecycle import Index
 from ..index.query import Query, Regex, normalize, parse
@@ -362,30 +363,33 @@ class SearchService:
         """
         if not batched:
             return [self.search(q, top_k=top_k) for q in queries]
-        qs = [parse(q) if isinstance(q, str) else q for q in queries]
-        results: list = [None] * len(qs)
-        to_fetch: list = []                      # deduplicated cold queries
-        pos_of: dict = {}                        # cache key -> to_fetch idx
-        assign: list[tuple[int, int]] = []       # (result slot, to_fetch idx)
-        for i, q in enumerate(qs):
-            key = self._cache_key(q, top_k)
-            hit = self._cache_get(key)
-            if hit is not None:
-                results[i] = hit
-                continue
-            pos = pos_of.get(key)
-            if pos is None:
-                pos = pos_of[key] = len(to_fetch)
-                to_fetch.append(q)
-            assign.append((i, pos))
-        if to_fetch:
-            batch = self.searcher.query_batch(
-                to_fetch, top_k=top_k, hedge=self.hedge, impl=impl)
-            # the whole batch shares its fetch rounds: ONE latency sample
-            self.stats.observe_batch([res.stats for res in batch])
-            self._observe_scatter()
-            for key, pos in pos_of.items():
-                self._cache_put(key, batch[pos])
-            for i, pos in assign:
-                results[i] = batch[pos]
-        return results
+        with spans.span("service.search_batch") as s:
+            qs = [parse(q) if isinstance(q, str) else q for q in queries]
+            results: list = [None] * len(qs)
+            to_fetch: list = []                  # deduplicated cold queries
+            pos_of: dict = {}                    # cache key -> to_fetch idx
+            assign: list[tuple[int, int]] = []   # (result slot, to_fetch idx)
+            for i, q in enumerate(qs):
+                key = self._cache_key(q, top_k)
+                hit = self._cache_get(key)
+                if hit is not None:
+                    results[i] = hit
+                    continue
+                pos = pos_of.get(key)
+                if pos is None:
+                    pos = pos_of[key] = len(to_fetch)
+                    to_fetch.append(q)
+                assign.append((i, pos))
+            s.set(n=len(qs), cold=len(to_fetch))
+            if to_fetch:
+                batch = self.searcher.query_batch(
+                    to_fetch, top_k=top_k, hedge=self.hedge, impl=impl)
+                # the whole batch shares its fetch rounds: ONE latency
+                # sample
+                self.stats.observe_batch([res.stats for res in batch])
+                self._observe_scatter()
+                for key, pos in pos_of.items():
+                    self._cache_put(key, batch[pos])
+                for i, pos in assign:
+                    results[i] = batch[pos]
+            return results

@@ -28,6 +28,11 @@ up in the load curves themselves.
 The registry is *passive*: layers that are handed a `Telemetry` record
 into it, layers that are not skip it entirely (`telemetry=None` is the
 default everywhere), so the data plane has zero new obligations.
+
+Where the time of one batch goes — plan, decode, bitmap build, kernel
+call, verification — is recorded by the span recorder
+(`analysis/spans.py`), which every layer can import; counts taken at a
+span's boundary are that span's attributes, not metrics here.
 """
 
 from __future__ import annotations
