@@ -47,6 +47,7 @@ survives as a deprecated shim over the transport adapter.
 
 from __future__ import annotations
 
+import math
 import warnings
 from dataclasses import dataclass, field, replace
 
@@ -567,7 +568,15 @@ def execute_jobs(units: list[Searcher], jobs: list[_Job], fetcher: _Fetcher,
                  hedge: bool = False, impl: str = "sorted",
                  batch_stats: BatchStats | None = None,
                  ) -> list[QueryResult]:
-    """Run a job batch over base + segments in two shared fetch rounds."""
+    """Run a job batch over base + segments in two shared fetch rounds.
+
+    A top-K job whose §IV-D samples hold fewer than `want` unique accepted
+    docs is completed from its remaining candidates in further rounds
+    (`_Completion`): each walks its units' permutations in order and stops
+    once the verified prefix proves the answer, which is the first `want`
+    unique accepted docs of u0.sample, u0.rest, u1.sample, ... — the same
+    refs, texts and order as fetching and verifying every remaining
+    candidate."""
     n_units = len(units)
     outs_per_unit, lstats = lookup_units(
         units, [j.lookup_q for j in jobs], fetcher, hedge=hedge)
@@ -630,41 +639,53 @@ def execute_jobs(units: list[Searcher], jobs: list[_Job], fetcher: _Fetcher,
         units, jobs, unit_job_refs, stats_of, fetcher,
         batch_docs=batch_docs)
 
-    # --- Eq. 6 failure (prob < delta) or tiny candidate set: fall back
-    # to fetching the remainder — again ONE batch for every unit of every
-    # job that came up short.
+    # --- Eq. 6 failure (prob < delta): complete every job whose samples
+    # came up short from its remaining candidates. The answer is defined
+    # by order alone — the first `want` unique accepted docs of u0.sample,
+    # u0.rest, u1.sample, u1.rest, ... (`_Completion`) — so each job walks
+    # that sequence in chunks sized from its own precision and stops once
+    # the prefix it has verified proves the answer. Each round is ONE
+    # batch for every unit of every job still short.
     with spans.span("searcher.fallback") as s:
-        fallback: list[dict[int, list[DocRef]]] = [{} for _ in units]
-        if any(jobs[j].top_k is not None for j in live):
-            for j in live:
-                if jobs[j].top_k is None:
-                    continue
-                # count unique doc identities — a doc accepted by
-                # several units (duplicate append) merges to ONE result,
-                # so a per-unit sum could skip a fallback the deduped set
-                # still needs
-                accepted = len({(r.blob, r.offset, r.length)
-                                for u in range(n_units)
-                                for r in refs_of[u][j]})
-                if accepted >= wants[j]:
-                    continue
-                for u in range(n_units):
-                    keys, lengths = combined[u][j]
-                    n_sampled = len(sampled[u][j][0])
-                    if len(keys) > n_sampled:
-                        rest = orders[u][j][n_sampled:]
-                        fallback[u][j] = units[u]._refs(keys[rest],
-                                                        lengths[rest])
-        if any(fallback):
+        walks: list[_Completion] = []
+        for j in live:
+            if jobs[j].top_k is None:
+                continue
+            # count unique doc identities — a doc accepted by several
+            # units (duplicate append) merges to ONE result, so a
+            # per-unit sum could skip a fallback the deduped set needs
+            accepted = len({_identity(r) for u in range(n_units)
+                            for r in refs_of[u][j]})
+            if accepted < wants[j]:
+                walks.append(_Completion(
+                    j, wants[j], [combined[u][j] for u in range(n_units)],
+                    [orders[u][j] for u in range(n_units)],
+                    [len(sampled[u][j][0]) for u in range(n_units)],
+                    [refs_of[u][j] for u in range(n_units)]))
+        remaining = sum(w.left() for w in walks)
+        n_rounds = n_docs = 0
+        fetched_jobs: set[int] = set()
+        while walks := [w for w in walks if not w.done()]:
+            fallback: list[dict[int, list[DocRef]]] = [{} for _ in units]
+            for w in walks:
+                for u, keys, lengths in w.take():
+                    fallback[u][w.j] = units[u]._refs(keys, lengths)
+                    n_docs += len(keys)
+                fetched_jobs.add(w.j)
             t2, r2 = _fetch_and_filter_units(units, jobs, fallback,
                                              stats_of, fetcher,
                                              batch_docs=batch_docs)
+            n_rounds += 1
             for u in range(n_units):
                 for j in fallback[u]:
                     texts_of[u][j] += t2[u][j]
                     refs_of[u][j] += r2[u][j]
-        s.set(jobs=len({j for f in fallback for j in f}),
-              docs=sum(len(r) for f in fallback for r in f.values()))
+            for w in walks:
+                for u in range(n_units):
+                    if w.j in fallback[u]:
+                        w.verified(u, len(fallback[u][w.j]), r2[u][w.j])
+        s.set(jobs=len(fetched_jobs), docs=n_docs, rounds=n_rounds,
+              remaining=remaining)
 
     # --- union per job across units (dedupe doc identity; non-top-K
     # results restored to the monolithic (blob, offset) order) -----------
@@ -681,6 +702,104 @@ def execute_jobs(units: list[Searcher], jobs: list[_Job], fetcher: _Fetcher,
             results[j] = QueryResult(refs=refs, texts=texts,
                                      stats=stats_of[j])
     return results  # type: ignore[return-value]
+
+
+FALLBACK_MIN_CHUNK = 32     # candidates: the smallest fallback chunk
+FALLBACK_MARGIN = 1.25      # a chunk's candidates per expected need
+
+
+def _identity(r: DocRef) -> tuple[str, int, int]:
+    return (r.blob, r.offset, r.length)
+
+
+class _Completion:
+    """One short top-K job's walk through its remaining candidates.
+
+    The answer of a top-K job whose samples came up short is the first
+    `want` unique accepted docs of the sequence u0.sample, u0.rest,
+    u1.sample, u1.rest, ..., each `rest` in its unit's `topk_order`
+    permutation: `_merge_results` unions the units in order and the merge
+    cuts at `want`. That answer is fixed by the shortest prefix of the
+    sequence holding `want` unique accepted docs, so the walk verifies
+    the sequence in order and is done once its verified prefix holds that
+    many, or the sequence ends. Whatever lies past the prefix only ever
+    came after the cut: the answer is the exhaustive fallback's, byte for
+    byte, with no more than the prefix (and one chunk's overshoot)
+    fetched.
+
+    A chunk is sized from what the job has observed: `FALLBACK_MARGIN` x
+    need / p candidates, p being the acceptance rate over the current
+    unit's sample and earlier chunks, never fewer than
+    `FALLBACK_MIN_CHUNK` nor fewer than twice the previous chunk, so a
+    job takes O(log R) rounds at most. A chunk of over half of what is
+    left takes all of it, as does p = 0: a job whose sample found nothing
+    still fetches its whole remainder in one round.
+    """
+
+    def __init__(self, j: int, want: int,
+                 candidates: list[tuple[np.ndarray, np.ndarray]],
+                 orders: list[np.ndarray], n_sampled: list[int],
+                 sample_refs: list[list[DocRef]]):
+        self.j, self.want = j, want
+        self.candidates, self.orders = candidates, orders
+        self.starts, self.ends = n_sampled, [len(o) for o in orders]
+        self.sample_ids = [{_identity(r) for r in refs}
+                           for refs in sample_refs]
+        self.checked = list(n_sampled)
+        self.accepted = [len(refs) for refs in sample_refs]
+        self.seen = set(self.sample_ids[0])
+        self.u, self.pos, self.prev = 0, n_sampled[0], 0
+        self._settle()
+
+    def _settle(self) -> None:
+        """Step past exhausted units; a unit's sample joins the prefix
+        as the walk reaches it."""
+        while self.u < len(self.ends) and self.pos >= self.ends[self.u]:
+            self.u += 1
+            if self.u < len(self.ends):
+                self.seen |= self.sample_ids[self.u]
+                self.pos = self.starts[self.u]
+
+    def done(self) -> bool:
+        return len(self.seen) >= self.want or self.u == len(self.ends)
+
+    def left(self) -> int:
+        """Unfetched candidates from the walk's position to the end."""
+        if self.u == len(self.ends):
+            return 0
+        return self.ends[self.u] - self.pos + sum(
+            e - s for s, e in zip(self.starts[self.u + 1:],
+                                  self.ends[self.u + 1:]))
+
+    def take(self) -> list[tuple[int, np.ndarray, np.ndarray]]:
+        """The next chunk, as `(unit, keys, lengths)` parts in order."""
+        left, u = self.left(), self.u
+        p = self.accepted[u] / self.checked[u] if self.checked[u] else 0.0
+        n = left
+        if p > 0:
+            need = self.want - len(self.seen)
+            n = max(math.ceil(FALLBACK_MARGIN * need / p),
+                    FALLBACK_MIN_CHUNK, 2 * self.prev)
+            if n > left / 2:
+                n = left
+        self.prev = n
+        parts = []
+        while n > 0 and not self.done():
+            lo = self.pos
+            t = min(n, self.ends[self.u] - lo)
+            idx = self.orders[self.u][lo:lo + t]
+            keys, lengths = self.candidates[self.u]
+            parts.append((self.u, keys[idx], lengths[idx]))
+            n -= t
+            self.pos += t
+            self._settle()
+        return parts
+
+    def verified(self, u: int, n_checked: int,
+                 accepted: list[DocRef]) -> None:
+        self.checked[u] += n_checked
+        self.accepted[u] += len(accepted)
+        self.seen.update(_identity(r) for r in accepted)
 
 
 def _merge_results(refs_lists: list[list[DocRef]],

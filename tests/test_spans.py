@@ -11,7 +11,7 @@ import pytest
 
 from repro.analysis import spans
 from repro.data import make_logs_like, write_corpus
-from repro.index import BuilderConfig, Index
+from repro.index import BuilderConfig, Index, searcher
 from repro.serving import SearchService
 from repro.storage import InMemoryBlobStore
 
@@ -181,12 +181,61 @@ def test_served_batch_span_tree_and_identical_results(service):
     verify = [s for s in out if s.name == "searcher.verify"]
     assert all(0 <= v.attrs["accepted"] <= v.attrs["docs"] for v in verify)
     # no line holds the phrase "info block": its sample comes up short
-    # and every remaining candidate goes through the fallback
+    # and, with nothing accepted to estimate from, every remaining
+    # candidate goes through the fallback in one round
     fallbacks = [s for s in out if s.name == "searcher.fallback"]
     assert any(fb.attrs["docs"] for fb in fallbacks)
-    for fb in fallbacks:
+    assert any(fb.attrs["rounds"] == 1
+               and fb.attrs["docs"] == fb.attrs["remaining"] > 0
+               for fb in fallbacks)
+    _check_fallbacks(out)
+
+
+def _check_fallbacks(out):
+    """Each fallback span: one `round2` and one `verify` inside it per
+    round, its `docs` the sum of theirs, no more than `remaining`."""
+    for fb in (s for s in out if s.name == "searcher.fallback"):
         inner = [s for s in out if s.parent == fb.id]
         assert {s.name for s in inner} <= {"searcher.round2",
                                            "searcher.verify"}
         assert fb.attrs["docs"] == sum(s.attrs["docs"] for s in inner
                                        if s.name == "searcher.verify")
+        for name in ("searcher.round2", "searcher.verify"):
+            assert fb.attrs["rounds"] == sum(s.name == name for s in inner)
+        assert fb.attrs["docs"] <= fb.attrs["remaining"]
+        assert (fb.attrs["jobs"] == 0) == (fb.attrs["rounds"] == 0)
+
+
+def test_fallback_span_over_several_rounds(monkeypatch):
+    """A phrase that half its candidates hold, K above what its sample
+    finds: the fallback completes it from a part of the remainder, in
+    several rounds when the chunks start small, with the same answer."""
+    lines = [f"INFO alpha beta gamma n{i}" if i % 2 else
+             f"WARN beta delta alpha n{i}" for i in range(1200)]
+    store = InMemoryBlobStore()
+    corpus = write_corpus(store, "corpus/r", lines, n_blobs=2)
+    Index.build(corpus, BuilderConfig(B=2000, F0=1.0), store,
+                "index/r").close()
+    svc = SearchService(Index.open(store, "index/r"))
+    try:
+        found = {}
+        for rule, (chunk, margin) in {"default": (32, 1.25),
+                                      "tight": (1, 0.01)}.items():
+            monkeypatch.setattr(searcher, "FALLBACK_MIN_CHUNK", chunk)
+            monkeypatch.setattr(searcher, "FALLBACK_MARGIN", margin)
+            spans.start()
+            res, = svc.search_batch(['"alpha beta"'], top_k=100,
+                                    impl="bitmap")
+            out = spans.stop()
+            _check_fallbacks(out)
+            fb, = (s for s in out if s.name == "searcher.fallback")
+            found[rule] = (res.refs, res.texts, fb.attrs)
+    finally:
+        svc.close()
+    assert found["tight"][:2] == found["default"][:2]
+    assert len(found["default"][0]) == 100
+    default, tight = found["default"][2], found["tight"][2]
+    assert default["jobs"] == tight["jobs"] == 1
+    assert 0 < default["docs"] * 4 < default["remaining"]
+    assert tight["rounds"] > 1
+    assert tight["remaining"] == default["remaining"]
