@@ -606,78 +606,94 @@ def combine_cluster_planned(plans_by_group: list[list[PhysicalPlan]],
     totals — exactly the round-1 statistics `shard_quotas` consumes.
     """
     from ..kernels.intersect import (combine_cluster, pack_cluster_programs,
+                                     pad_to_shape_class,
                                      postings_to_bitmap_batch)
 
     G = len(plans_by_group)
     Q = len(plans_by_group[0]) if G else 0
     if not G or not Q:
         return [[] for _ in range(G)], np.zeros((G, Q), dtype=np.int64)
-    compiled = [[_compile_steps(plans_by_group[g][q],
-                                per_words_by_group[g][q],
-                                is_common_by_group[g])
-                 for q in range(Q)] for g in range(G)]
-    universes: list[list[np.ndarray | None]] = \
-        [[None] * Q for _ in range(G)]
-    rows: list[list[list[np.ndarray]]] = [[[] for _ in range(Q)]
-                                          for _ in range(G)]
-    programs: list[list[list[tuple[int, int, int]]]] = \
-        [[[] for _ in range(Q)] for _ in range(G)]
-    for g in range(G):
-        for q in range(Q):
-            leaves, steps = compiled[g][q]
-            keys_list = [k for k, _l in leaves]
-            uni = np.unique(np.concatenate(keys_list)) if keys_list else \
-                np.empty(0, np.uint64)
-            if not len(uni):
-                # placeholder block: layer 0 of the zero-filled tensor is
-                # all-zero, so AND(0, 0) evaluates to the empty set the
-                # grid still needs a program for
-                programs[g][q] = [(OP_AND, 0, 0)]
-                continue
-            universes[g][q] = uni
-            rows[g][q] = [np.searchsorted(uni, k).astype(np.uint32)
-                          for k in keys_list]
-            programs[g][q] = steps
-    n_bits = max((len(u) for row in universes for u in row
-                  if u is not None), default=1)
-    L_max = max(max((len(r) for r in row), default=0)
-                for row in rows) or 1
-    W = (n_bits + 31) // 32
-    bitmaps = np.zeros((G, Q, L_max, W), dtype=np.uint32)
-    padded: list[list[list[tuple[int, int, int]]]] = \
-        [[[] for _ in range(Q)] for _ in range(G)]
-    for g in range(G):
-        for q in range(Q):
-            posts = rows[g][q]
-            if posts:
-                bitmaps[g, q, :len(posts)] = postings_to_bitmap_batch(
-                    [posts], n_bits)[0, :len(posts)]
-                # re-point step slots at the padded layer count
-                shift = L_max - len(posts)
-                padded[g][q] = [(op,
-                                 a + shift if a >= len(posts) else a,
-                                 b + shift if b >= len(posts) else b)
-                                for op, a, b in programs[g][q]]
-            else:
-                padded[g][q] = programs[g][q]      # zero-layer identity
-    progs = pack_cluster_programs(padded, L_max)
-    inter, counts = combine_cluster(bitmaps, progs)
-    inter = np.asarray(inter)
-    results: list[list[tuple[np.ndarray, np.ndarray]]] = \
-        [[(np.empty(0, np.uint64), np.empty(0, np.uint64))] * Q
-         for _ in range(G)]
-    for g in range(G):
-        for q in range(Q):
-            uni = universes[g][q]
-            if uni is None:
-                continue
-            bits = np.unpackbits(inter[g, q].view(np.uint8),
-                                 bitorder="little")
-            sel = np.flatnonzero(bits[:len(uni)])
-            keys = uni[sel].astype(np.uint64, copy=False)
-            leaves, _steps = compiled[g][q]
-            results[g][q] = (keys, _recover_lengths(keys, leaves))
-    return results, np.asarray(counts).astype(np.int64)
+    with spans.span("combine.build") as s:
+        compiled = [[_compile_steps(plans_by_group[g][q],
+                                    per_words_by_group[g][q],
+                                    is_common_by_group[g])
+                     for q in range(Q)] for g in range(G)]
+        universes: list[list[np.ndarray | None]] = \
+            [[None] * Q for _ in range(G)]
+        rows: list[list[list[np.ndarray]]] = [[[] for _ in range(Q)]
+                                              for _ in range(G)]
+        programs: list[list[list[tuple[int, int, int]]]] = \
+            [[[] for _ in range(Q)] for _ in range(G)]
+        for g in range(G):
+            for q in range(Q):
+                leaves, steps = compiled[g][q]
+                keys_list = [k for k, _l in leaves]
+                uni = np.unique(np.concatenate(keys_list)) if keys_list else \
+                    np.empty(0, np.uint64)
+                if not len(uni):
+                    # placeholder block: layer 0 of the zero-filled tensor is
+                    # all-zero, so AND(0, 0) evaluates to the empty set the
+                    # grid still needs a program for
+                    programs[g][q] = [(OP_AND, 0, 0)]
+                    continue
+                universes[g][q] = uni
+                rows[g][q] = [np.searchsorted(uni, k).astype(np.uint32)
+                              for k in keys_list]
+                programs[g][q] = steps
+        n_bits = max((len(u) for row in universes for u in row
+                      if u is not None), default=1)
+        L_max = max(max((len(r) for r in row), default=0)
+                    for row in rows) or 1
+        W = (n_bits + 31) // 32
+        bitmaps = np.zeros((G, Q, L_max, W), dtype=np.uint32)
+        padded: list[list[list[tuple[int, int, int]]]] = \
+            [[[] for _ in range(Q)] for _ in range(G)]
+        for g in range(G):
+            for q in range(Q):
+                posts = rows[g][q]
+                if posts:
+                    bitmaps[g, q, :len(posts)] = postings_to_bitmap_batch(
+                        [posts], n_bits)[0, :len(posts)]
+                    # re-point step slots at the padded layer count
+                    shift = L_max - len(posts)
+                    padded[g][q] = [(op,
+                                     a + shift if a >= len(posts) else a,
+                                     b + shift if b >= len(posts) else b)
+                                    for op, a, b in programs[g][q]]
+                else:
+                    padded[g][q] = programs[g][q]      # zero-layer identity
+        progs = pack_cluster_programs(padded, L_max)
+        # the work the batch asks for, before its shape class pads it
+        shape = dict(kernel="combine_cluster", G=G, Q=Q, L=L_max, W=W,
+                     S=progs.shape[2])
+        s.set(**shape)
+        bitmaps, progs = pad_to_shape_class(bitmaps, progs)
+    with spans.span("combine.call") as s:
+        s.set(**shape)
+        inter, counts = combine_cluster(bitmaps, progs)
+        # the chip may hand the (G, Q, W) result back in a layout other
+        # than C order (W outermost when W is a few words), where a
+        # row's words are not adjacent and `.view` cannot reinterpret
+        # them: copy it to C order, whose values are the same
+        inter = np.ascontiguousarray(np.asarray(inter)[:, :Q, :W])
+        counts = np.asarray(counts)[:, :Q].astype(np.int64)
+    with spans.span("combine.unpack") as s:
+        s.set(**shape)
+        results: list[list[tuple[np.ndarray, np.ndarray]]] = \
+            [[(np.empty(0, np.uint64), np.empty(0, np.uint64))] * Q
+             for _ in range(G)]
+        for g in range(G):
+            for q in range(Q):
+                uni = universes[g][q]
+                if uni is None:
+                    continue
+                bits = np.unpackbits(inter[g, q].view(np.uint8),
+                                     bitorder="little")
+                sel = np.flatnonzero(bits[:len(uni)])
+                keys = uni[sel].astype(np.uint64, copy=False)
+                leaves, _steps = compiled[g][q]
+                results[g][q] = (keys, _recover_lengths(keys, leaves))
+    return results, counts
 
 
 # ----------------------------------------------------- global top-K budget

@@ -708,6 +708,22 @@ FALLBACK_MIN_CHUNK = 32     # candidates: the smallest fallback chunk
 FALLBACK_MARGIN = 1.25      # a chunk's candidates per expected need
 
 
+def fallback_chunk(need: int, p: float, prev: int, left: int) -> int:
+    """Candidates the next round of a top-K completion fetches.
+
+    `need` more accepted documents are wanted, `p` is the acceptance rate
+    observed so far, `prev` the previous chunk and `left` the candidates
+    not yet fetched: `FALLBACK_MARGIN` x need / p, never fewer than
+    `FALLBACK_MIN_CHUNK` nor than twice `prev`, so a walk takes O(log R)
+    rounds at most. A chunk of over half of what is left takes all of it,
+    as does p = 0: nothing to estimate from."""
+    if p <= 0:
+        return left
+    n = max(math.ceil(FALLBACK_MARGIN * need / p), FALLBACK_MIN_CHUNK,
+            2 * prev)
+    return left if n > left / 2 else n
+
+
 def _identity(r: DocRef) -> tuple[str, int, int]:
     return (r.blob, r.offset, r.length)
 
@@ -727,13 +743,10 @@ class _Completion:
     byte, with no more than the prefix (and one chunk's overshoot)
     fetched.
 
-    A chunk is sized from what the job has observed: `FALLBACK_MARGIN` x
-    need / p candidates, p being the acceptance rate over the current
-    unit's sample and earlier chunks, never fewer than
-    `FALLBACK_MIN_CHUNK` nor fewer than twice the previous chunk, so a
-    job takes O(log R) rounds at most. A chunk of over half of what is
-    left takes all of it, as does p = 0: a job whose sample found nothing
-    still fetches its whole remainder in one round.
+    A chunk is sized by `fallback_chunk` from what the job has observed,
+    p being the acceptance rate over the current unit's sample and
+    earlier chunks: a job whose sample found nothing still fetches its
+    whole remainder in one round.
     """
 
     def __init__(self, j: int, want: int,
@@ -773,16 +786,10 @@ class _Completion:
 
     def take(self) -> list[tuple[int, np.ndarray, np.ndarray]]:
         """The next chunk, as `(unit, keys, lengths)` parts in order."""
-        left, u = self.left(), self.u
+        u = self.u
         p = self.accepted[u] / self.checked[u] if self.checked[u] else 0.0
-        n = left
-        if p > 0:
-            need = self.want - len(self.seen)
-            n = max(math.ceil(FALLBACK_MARGIN * need / p),
-                    FALLBACK_MIN_CHUNK, 2 * self.prev)
-            if n > left / 2:
-                n = left
-        self.prev = n
+        n = self.prev = fallback_chunk(self.want - len(self.seen), p,
+                                       self.prev, self.left())
         parts = []
         while n > 0 and not self.done():
             lo = self.pos

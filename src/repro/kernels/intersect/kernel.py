@@ -212,19 +212,11 @@ def _combine_call(programs: jnp.ndarray, x: jnp.ndarray, rows: int,
     )(programs.reshape(-1), x)
 
 
-@functools.partial(jax.jit, static_argnames=("interpret",))
-def combine_batch_pallas(bitmaps: jnp.ndarray, programs: jnp.ndarray, *,
-                         interpret: bool,
-                         ) -> tuple[jnp.ndarray, jnp.ndarray]:
-    """bitmaps: (Q, L, W) uint32, programs: (Q, S, 3) int32 rows of
-    (opcode, slot_a, slot_b) → (result bitmaps (Q, W), counts (Q,)).
-
-    Grid is (query, tile): program (q, i) evaluates query q's combine
-    program on its i-th document tile — a whole batch of arbitrary
-    boolean trees (AND/OR/ANDNOT) combines in one fused pass. A batch
-    whose programs outgrow `PROGRAM_WORDS` of SMEM runs as several
-    `pallas_call`s over slices of the query axis.
-    """
+def _combine_flat(bitmaps: jnp.ndarray, programs: jnp.ndarray,
+                  interpret: bool) -> tuple[jnp.ndarray, jnp.ndarray]:
+    """The body of `combine_batch_pallas`, traced inside each entry
+    point's own `jit`, so the chip's trace names each entry point's
+    kernel after it (`combine_batch_pallas.N`, `combine_cluster_pallas.N`)."""
     Q, L, W = bitmaps.shape
     S = programs.shape[1]
     rows = _tile_rows(W, 3 * L + S + 2)
@@ -239,6 +231,22 @@ def combine_batch_pallas(bitmaps: jnp.ndarray, programs: jnp.ndarray, *,
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
+def combine_batch_pallas(bitmaps: jnp.ndarray, programs: jnp.ndarray, *,
+                         interpret: bool,
+                         ) -> tuple[jnp.ndarray, jnp.ndarray]:
+    """bitmaps: (Q, L, W) uint32, programs: (Q, S, 3) int32 rows of
+    (opcode, slot_a, slot_b) → (result bitmaps (Q, W), counts (Q,)).
+
+    Grid is (query, tile): program (q, i) evaluates query q's combine
+    program on its i-th document tile — a whole batch of arbitrary
+    boolean trees (AND/OR/ANDNOT) combines in one fused pass. A batch
+    whose programs outgrow `PROGRAM_WORDS` of SMEM runs as several
+    `pallas_call`s over slices of the query axis.
+    """
+    return _combine_flat(bitmaps, programs, interpret)
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
 def combine_cluster_pallas(bitmaps: jnp.ndarray, programs: jnp.ndarray, *,
                            interpret: bool,
                            ) -> tuple[jnp.ndarray, jnp.ndarray]:
@@ -250,10 +258,11 @@ def combine_cluster_pallas(bitmaps: jnp.ndarray, programs: jnp.ndarray, *,
     combine round — every shard's every query's boolean program — runs
     in ONE fused pass; the (G, Q) candidate counts come back for free,
     feeding the Eq. 6 global top-K sampling budget without a second
-    reduction pass.
+    reduction pass. The result may come back to the host in a layout
+    other than C order; callers read it through `np.ascontiguousarray`.
     """
     G, Q, L, W = bitmaps.shape
-    out, counts = combine_batch_pallas(
+    out, counts = _combine_flat(
         bitmaps.reshape(G * Q, L, W),
-        programs.reshape(G * Q, *programs.shape[2:]), interpret=interpret)
+        programs.reshape(G * Q, *programs.shape[2:]), interpret)
     return out.reshape(G, Q, W), counts.reshape(G, Q)

@@ -128,6 +128,45 @@ def pack_cluster_programs(programs: list[list[list[tuple[int, int, int]]]],
     return flat.reshape(len(programs), Q, flat.shape[1], 3)
 
 
+W_CLASS_MIN = 1024     # words: the narrowest bitmap width class
+
+
+def shape_class(n: int, least: int = 1) -> int:
+    """The power of two at or above `n`, and at least `least`."""
+    return max(least, 1 << max(n - 1, 0).bit_length())
+
+
+def pad_to_shape_class(bitmaps: np.ndarray, programs: np.ndarray
+                       ) -> tuple[np.ndarray, np.ndarray]:
+    """Pad a combine batch to its shape class, so that a stream of
+    batches of many shapes compiles a few kernels, not one per shape.
+
+    bitmaps (..., Q, L, W) uint32 and programs (..., Q, S, 3) int32, as
+    `combine_batch` and `combine_cluster` take them. Q, L and S go up to
+    a power of two, W to one of at least `W_CLASS_MIN` words (as far as
+    the kernel's own tiles pad a narrower one). Added queries, layers
+    and words are zero; added steps chain the last result through
+    (AND(r, r)), and step slots move up past the added layers. The
+    result's first Q rows, cut to W words, and their counts are the
+    unpadded batch's.
+    """
+    *lead, Q, L, W = bitmaps.shape
+    S = programs.shape[-2]
+    Qc, Lc, Sc = shape_class(Q), shape_class(L), shape_class(S)
+    x = np.zeros((*lead, Qc, Lc, shape_class(W, W_CLASS_MIN)), np.uint32)
+    x[..., :Q, :L, :W] = bitmaps
+    chain = Lc + np.arange(Sc) - 1
+    chain[0] = 0                       # an added query: AND(layer 0, 0)
+    p = np.empty((*lead, Qc, Sc, 3), np.int32)
+    p[..., 0] = OP_AND
+    p[..., 1] = p[..., 2] = chain
+    given = np.array(programs, dtype=np.int32)
+    slots = given[..., 1:]
+    slots[slots >= L] += Lc - L
+    p[..., :Q, :S, :] = given
+    return x, p
+
+
 def combine_batch(bitmaps, programs, impl: str = "pallas"):
     """Evaluate per-query AND/OR/ANDNOT programs over layered bitsets.
 

@@ -68,7 +68,9 @@ from concurrent.futures import FIRST_COMPLETED, ThreadPoolExecutor, wait
 from dataclasses import asdict, dataclass, field, replace
 
 import msgpack
+import numpy as np
 
+from ..analysis import spans
 from ..analysis.locks import OrderedLock
 from ..core.hashing import word_fingerprint
 from ..core.topk import sample_size
@@ -84,7 +86,7 @@ from ..index.planner import (DocContent, combine_cluster_planned,
 from ..index.query import Query, Regex
 from ..index.searcher import (BatchStats, QueryResult, QueryStats, Searcher,
                               _filter_unit_candidates, _merge_results,
-                              lookup_units, topk_order)
+                              fallback_chunk, lookup_units, topk_order)
 from ..storage.blobstore import RangeRequest
 from ..storage.cache import SuperpostCache
 from ..storage.simcloud import FetchStats
@@ -1512,19 +1514,21 @@ class ClusterSearcher:
         the round costs the slowest shard; shards sharing one simulated
         clock fall back to a deterministic sequential drive.
 
-        `fused=True` (default: the session's `fused` flag) switches to
-        the cluster-fused path: shards only run round 1, every (shard,
-        query) candidate combine executes in ONE Pallas launch on the
-        gather side, and round-2 document work scatters back out under a
-        top-K sampling `budget` — `"global"` evaluates Eq. 6 once over
-        the pooled cluster candidates (~k docs total), `"per_shard"`
-        evaluates it independently per shard unit (~N·k docs, the
-        unbudgeted baseline). Both budgets return byte-identical
-        results: the final top-K is always the first k accepted docs in
-        the canonical candidate order, and a completion round fetches
-        whatever the initial quota left unproven.
+        `fused=True` (default: the session's `fused` flag, or
+        `impl="fused"`) switches to the cluster-fused path: shards only
+        run round 1, every (shard, query) candidate combine executes in
+        ONE Pallas launch on the gather side, and round-2 document work
+        scatters back out under a top-K sampling `budget` — `"global"`
+        evaluates Eq. 6 once over the pooled cluster candidates (~k
+        docs total), `"per_shard"` evaluates it independently per shard
+        unit (~N·k docs, the unbudgeted baseline). Both budgets return
+        byte-identical results: the final top-K is always the first k
+        accepted docs in the canonical candidate order, and completion
+        rounds walk that order until the initial quota's shortfall is
+        proven (`_Canonical`).
         """
-        fused = self.fused if fused is None else fused
+        if fused is None:
+            fused = self.fused or impl == "fused"
         if fused:
             return self._query_batch_fused(queries, top_k, hedge, budget)
         concurrent = self.concurrent and self._independent_clocks()
@@ -1584,31 +1588,6 @@ class ClusterSearcher:
         t1 = clock.clock_s if clock is not None else time.perf_counter()
         return payloads, fstats, t1 - t0
 
-    @staticmethod
-    def _next_pending(st: dict, top_k: int | None) -> set:
-        """Completion step of the budget loop.
-
-        The final answer is defined as the first `top_k` ACCEPTED docs
-        in the canonical candidate order — a property of the candidate
-        sets, the verifier, and the shared §IV-D permutations alone, so
-        it is independent of whatever the initial quota policy selected
-        (this is what makes "global" and "per_shard" budgets
-        byte-identical). With k docs accepted, any unfetched candidate
-        ranked before the k-th accepted could still displace it — fetch
-        exactly those; with fewer than k accepted, fall back to the
-        unbudgeted fetch (everything left). Each branch strictly shrinks
-        the unproven set, so the loop terminates in <= 2 extra rounds
-        past the initial quota fetch."""
-        canon, fetched = st["canon"], st["fetched"]
-        if top_k is None:
-            return set()          # everything was selected up front
-        accepted = [i for i in canon if st["accepted"].get(i)]
-        if len(accepted) < top_k:
-            return {i for i in canon if i not in fetched}
-        threshold = st["prio"][accepted[top_k - 1]]
-        return {i for i in canon
-                if i not in fetched and st["prio"][i] < threshold}
-
     def _query_batch_fused(self, queries, top_k, hedge, budget,
                            ) -> list[QueryResult]:
         """Phase-split scatter-gather: concurrent round-1 legs → ONE
@@ -1629,14 +1608,17 @@ class ClusterSearcher:
             picked.append((i, replicas[i]))
         try:
             # --- phase 1: per-shard superpost rounds (concurrent) -------
-            if concurrent and n_shards > 1:
-                futs = [self._executor().submit(
-                    self._fused_round1, r, queries, top_k, hedge)
-                    for _i, r in picked]
-                legs = [f.result() for f in futs]
-            else:
-                legs = [self._fused_round1(r, queries, top_k, hedge)
+            with spans.span("cluster.round1") as s:
+                if concurrent and n_shards > 1:
+                    futs = [self._executor().submit(
+                        self._fused_round1, r, queries, top_k, hedge)
                         for _i, r in picked]
+                    legs = [f.result() for f in futs]
+                else:
+                    legs = [self._fused_round1(r, queries, top_k, hedge)
+                            for _i, r in picked]
+                s.set(shards=n_shards,
+                      requests=sum(leg[3].lookup.n_requests for leg in legs))
 
             # --- phase 2: ONE fused combine over (shard, query) ---------
             # groups flatten every shard's units; group order is
@@ -1662,55 +1644,33 @@ class ClusterSearcher:
             F0s = [unit.F0 for _si, unit in groups]
 
             # --- phase 3: quotas + canonical candidate order per job ----
-            job_state: list[dict] = []
-            for j in range(Q):
-                per_group_refs: list[list[DocRef]] = []
-                R_gs: list[int] = []
-                for g, (si, unit) in enumerate(groups):
-                    keys, lengths = combined[g][j]
-                    # aliased units serve a slot subset of their source
-                    # blobs: drop out-of-slot candidates BEFORE the
-                    # permutation and the quota computation, so budgets
-                    # and tie-breaks match a physical shard exactly
-                    keys, lengths = _filter_unit_candidates(unit, keys,
-                                                            lengths)
-                    if top_k is not None and len(keys):
-                        order = topk_order(keys)
-                        keys, lengths = keys[order], lengths[order]
-                    per_group_refs.append(unit._refs(keys, lengths))
-                    R_gs.append(len(keys))
-                # dedup into the canonical order: priority = (rank in the
-                # group's permutation, group); a doc indexed by several
-                # units keeps its smallest priority
-                prio: dict[tuple, tuple] = {}
-                ref_of: dict[tuple, DocRef] = {}
-                shard_of: dict[tuple, int] = {}
-                for g, refs in enumerate(per_group_refs):
-                    si = groups[g][0]
-                    for rank, ref in enumerate(refs):
-                        ident = (ref.blob, ref.offset, ref.length)
-                        p = (rank, g)
-                        if ident not in prio or p < prio[ident]:
-                            prio[ident] = p
-                            ref_of[ident] = ref
-                            shard_of[ident] = si
-                canon = sorted(prio, key=lambda i: prio[i])
-                delta = legs[0][1][j].delta
-                if top_k is None:
-                    quotas = R_gs
-                elif budget == "global":
-                    quotas = shard_quotas(R_gs, top_k, F0s, delta)
-                else:    # per_shard: independent Eq. 6 per group (~N·k)
-                    quotas = [sample_size(R, top_k, f0, delta) if R else 0
-                              for R, f0 in zip(R_gs, F0s)]
-                pending: set = set()
-                for g, refs in enumerate(per_group_refs):
-                    for ref in refs[:quotas[g]]:
-                        pending.add((ref.blob, ref.offset, ref.length))
-                job_state.append(dict(
-                    prio=prio, ref_of=ref_of, shard_of=shard_of,
-                    canon=canon, pending=pending, fetched=set(),
-                    accepted={}))
+            with spans.span("cluster.order") as s:
+                job_state: list[_Canonical] = []
+                for j in range(Q):
+                    cands: list[tuple[np.ndarray, np.ndarray]] = []
+                    for g, (si, unit) in enumerate(groups):
+                        keys, lengths = combined[g][j]
+                        # aliased units serve a slot subset of their source
+                        # blobs: drop out-of-slot candidates BEFORE the
+                        # permutation and the quota computation, so budgets
+                        # and tie-breaks match a physical shard exactly
+                        keys, lengths = _filter_unit_candidates(unit, keys,
+                                                                lengths)
+                        if top_k is not None and len(keys):
+                            order = topk_order(keys)
+                            keys, lengths = keys[order], lengths[order]
+                        cands.append((keys, lengths))
+                    R_gs = [len(k) for k, _l in cands]
+                    delta = legs[0][1][j].delta
+                    if top_k is None:
+                        quotas = R_gs
+                    elif budget == "global":
+                        quotas = shard_quotas(R_gs, top_k, F0s, delta)
+                    else:    # per_shard: independent Eq. 6 per group (~N·k)
+                        quotas = [sample_size(R, top_k, f0, delta) if R
+                                  else 0 for R, f0 in zip(R_gs, F0s)]
+                    job_state.append(_Canonical(groups, cands, quotas))
+                s.set(candidates=sum(st.n_positions for st in job_state))
 
             # --- phase 4: budgeted round-2 scatter + completion loop ----
             round2_stats = [FetchStats() for _ in range(n_shards)]
@@ -1719,49 +1679,78 @@ class ClusterSearcher:
             texts_cache: dict[tuple, str] = {}
             content_cache: dict[tuple, DocContent] = {}
             fp_count = [0] * Q
-            while any(st["pending"] for st in job_state):
-                per_shard_idents: list[list[tuple]] = \
-                    [[] for _ in range(n_shards)]
-                queued: set = set()
-                for st in job_state:
-                    for ident in st["pending"]:
-                        if ident not in queued and ident not in texts_cache:
-                            queued.add(ident)
-                            per_shard_idents[st["shard_of"][ident]].append(
-                                ident)
 
-                def fetch_leg(si: int):
-                    idents = per_shard_idents[si]
-                    if not idents:
-                        return [], FetchStats(), 0.0
-                    return self._fused_fetch(
-                        picked[si][1],
-                        [RangeRequest(*ident) for ident in idents])
+            def fetch_round() -> int:
+                """Fetch every job's pending docs (each once), verify them
+                per job and set each job's next pending set; returns the
+                documents verified."""
+                nonlocal n_rounds2
+                with spans.span("cluster.round2") as s:
+                    per_shard_idents: list[list[tuple]] = \
+                        [[] for _ in range(n_shards)]
+                    queued: set = set()
+                    for st in job_state:
+                        for ident in st.pending:
+                            if ident not in queued \
+                                    and ident not in texts_cache:
+                                queued.add(ident)
+                                per_shard_idents[st.shard_of[ident]].append(
+                                    ident)
+                    s.set(requests=len(queued))
 
-                if concurrent and n_shards > 1:
-                    futs = [self._executor().submit(fetch_leg, si)
-                            for si in range(n_shards)]
-                    legs2 = [f.result() for f in futs]
-                else:
-                    legs2 = [fetch_leg(si) for si in range(n_shards)]
-                for si, (payloads, fstats, elapsed) in enumerate(legs2):
-                    round2_stats[si].add(fstats)
-                    round2_elapsed[si] += elapsed
-                    for ident, payload in zip(per_shard_idents[si],
-                                              payloads):
-                        texts_cache[ident] = payload.decode("utf-8")
+                    def fetch_leg(si: int):
+                        idents = per_shard_idents[si]
+                        if not idents:
+                            return [], FetchStats(), 0.0
+                        return self._fused_fetch(
+                            picked[si][1],
+                            [RangeRequest(*ident) for ident in idents])
+
+                    if concurrent and n_shards > 1:
+                        futs = [self._executor().submit(fetch_leg, si)
+                                for si in range(n_shards)]
+                        legs2 = [f.result() for f in futs]
+                    else:
+                        legs2 = [fetch_leg(si) for si in range(n_shards)]
+                    for si, (payloads, fstats, elapsed) in enumerate(legs2):
+                        round2_stats[si].add(fstats)
+                        round2_elapsed[si] += elapsed
+                        for ident, payload in zip(per_shard_idents[si],
+                                                  payloads):
+                            texts_cache[ident] = payload.decode("utf-8")
                 n_rounds2 += 1
 
-                for j, st in enumerate(job_state):
-                    for ident in st["pending"]:
-                        st["fetched"].add(ident)
-                        job = legs[st["shard_of"][ident]][1][j]
-                        ok = _accept(job, ident, texts_cache[ident],
-                                     content_cache)
-                        st["accepted"][ident] = ok
-                        if not ok:
-                            fp_count[j] += 1
-                    st["pending"] = self._next_pending(st, top_k)
+                docs = accepted = 0
+                with spans.span("cluster.verify") as s:
+                    for j, st in enumerate(job_state):
+                        for ident in st.pending:
+                            job = legs[st.shard_of[ident]][1][j]
+                            ok = _accept(job, ident, texts_cache[ident],
+                                         content_cache)
+                            st.verified(ident, ok)
+                            docs += 1
+                            accepted += ok
+                            if not ok:
+                                fp_count[j] += 1
+                    s.set(docs=docs, accepted=accepted)
+                for st in job_state:
+                    if st.pending:
+                        st.pending = st.next_pending(top_k)
+                return docs
+
+            # the quota round, then the completion: every job whose
+            # quotas did not prove its top-K walks the canonical order on
+            if any(st.pending for st in job_state):
+                fetch_round()
+            with spans.span("cluster.completion") as s:
+                walked = {j for j, st in enumerate(job_state) if st.pending}
+                remaining = sum(job_state[j].unfetched() for j in walked)
+                n_rounds = n_docs = 0
+                while any(st.pending for st in job_state):
+                    n_docs += fetch_round()
+                    n_rounds += 1
+                s.set(jobs=len(walked), docs=n_docs, rounds=n_rounds,
+                      remaining=remaining)
 
             # --- gather: canonical selection + stats --------------------
             lookup_merged = _merge_fetch([leg[3].lookup for leg in legs],
@@ -1769,14 +1758,12 @@ class ClusterSearcher:
             docs_merged = _merge_fetch(round2_stats, concurrent)
             results: list[QueryResult] = []
             for j, st in enumerate(job_state):
-                accepted = [i for i in st["canon"]
-                            if st["accepted"].get(i)]
                 if top_k is not None:
-                    chosen = accepted[:top_k]
+                    chosen = st.first_accepted(top_k)
                 else:
                     # non-top-K: monolithic (blob, offset) order, same as
                     # the non-fused merge
-                    chosen = sorted(accepted)
+                    chosen = sorted(i for i, ok in st.verdict.items() if ok)
                 stats = QueryStats(
                     lookup=replace(lookup_merged),
                     docs=replace(docs_merged),
@@ -1785,7 +1772,7 @@ class ClusterSearcher:
                     n_results=len(chosen),
                     rounds=1 + n_rounds2)
                 results.append(QueryResult(
-                    refs=[st["ref_of"][i] for i in chosen],
+                    refs=[st.ref_of[i] for i in chosen],
                     texts=[texts_cache[i] for i in chosen],
                     stats=stats))
 
@@ -1885,6 +1872,132 @@ def _accept(job, ident: tuple, text: str,
     if job.accept_doc is not None:
         return job.accept_doc(content)
     return job.accept_words(content.words)
+
+
+class _Canonical:
+    """One job's candidates across the gather's groups, in canonical order.
+
+    Group g's candidates come in its `topk_order` permutation (index
+    order without top-K). A candidate's priority is (rank in its group,
+    g); a document several groups hold keeps its smallest, and the
+    canonical order sorts by priority. The top-K answer is the first K
+    accepted documents of that order — fixed by the candidate sets, the
+    verifier and the permutations alone, so it is the same whatever the
+    quotas fetched first (which is what makes the "global" and
+    "per_shard" budgets byte-identical).
+
+    `pending` starts as each group's first `quotas[g]` candidates. After
+    each round `next_pending` proves the answer or walks on:
+
+      * with K accepted, any unfetched candidate ranked before the K-th
+        accepted could still displace it: fetch exactly those;
+      * with fewer, fetch the next unfetched candidates of the canonical
+        order, as many as `searcher.fallback_chunk` sizes from the need
+        and the acceptance rate so far — the single index's completion
+        rule, over this order — and stop once the answer is proven.
+
+    `DocRef`s are built only for the candidates a round fetches and the
+    canonical prefix the walk reaches, never for the whole order.
+    """
+
+    def __init__(self, groups: list[tuple[int, Searcher]],
+                 cands: list[tuple[np.ndarray, np.ndarray]],
+                 quotas: list[int]) -> None:
+        self.groups, self.cands = groups, cands
+        self.sizes = [len(k) for k, _l in cands]
+        self.n_positions = sum(self.sizes)
+        self.idents: list[tuple] = []     # canonical prefix, deduplicated
+        self._listed: set = set()
+        self.rank = 0                      # every group's ranks below it
+        self.ref_of: dict[tuple, DocRef] = {}
+        self.shard_of: dict[tuple, int] = {}
+        self.verdict: dict[tuple, bool] = {}   # every fetched doc
+        self.n_accepted = 0
+        self.prev = 0
+        self.pending: set = set()
+        for g, q in enumerate(quotas):
+            if q:
+                self.pending.update(self._refs(g, np.arange(q)))
+
+    def _refs(self, g: int, ranks: np.ndarray) -> list[tuple]:
+        """Identities of group g's candidates at `ranks`, recording
+        their refs and shards."""
+        si, unit = self.groups[g]
+        keys, lengths = self.cands[g]
+        out = []
+        for ref in unit._refs(keys[ranks], lengths[ranks]):
+            ident = (ref.blob, ref.offset, ref.length)
+            self.ref_of.setdefault(ident, ref)
+            self.shard_of.setdefault(ident, si)
+            out.append(ident)
+        return out
+
+    def _extend(self) -> None:
+        """List the next block of ranks (doubling), rank by rank and
+        group by group within a rank."""
+        lo = self.rank
+        hi = min(max(self.sizes), lo + max(8, lo))
+        block = [(g, self._refs(g, np.arange(lo, min(hi, n))))
+                 for g, n in enumerate(self.sizes) if n > lo]
+        for i in range(hi - lo):
+            for _g, idents in block:
+                if i < len(idents) and idents[i] not in self._listed:
+                    self._listed.add(idents[i])
+                    self.idents.append(idents[i])
+        self.rank = hi
+
+    def walk(self):
+        """Document identities in canonical order."""
+        i = 0
+        while True:
+            if i == len(self.idents):
+                if self.rank >= max(self.sizes, default=0):
+                    return
+                self._extend()
+                continue
+            yield self.idents[i]
+            i += 1
+
+    def verified(self, ident: tuple, ok: bool) -> None:
+        self.verdict[ident] = ok
+        self.n_accepted += ok
+
+    def unfetched(self) -> int:
+        return self.n_positions - len(self.verdict)
+
+    def first_accepted(self, k: int) -> list[tuple]:
+        out = []
+        for ident in self.walk():
+            if len(out) == k:
+                break
+            if self.verdict.get(ident):
+                out.append(ident)
+        return out
+
+    def next_pending(self, top_k: int | None) -> set:
+        if top_k is None:
+            return set()          # everything was selected up front
+        out: set = set()
+        if self.n_accepted >= top_k:
+            seen = 0
+            for ident in self.walk():
+                ok = self.verdict.get(ident)
+                if ok is None:
+                    out.add(ident)
+                elif ok:
+                    seen += 1
+                    if seen == top_k:
+                        break
+            return out
+        p = self.n_accepted / len(self.verdict) if self.verdict else 0.0
+        n = self.prev = fallback_chunk(top_k - self.n_accepted, p,
+                                       self.prev, self.unfetched())
+        for ident in self.walk():
+            if len(out) == n:
+                break
+            if ident not in self.verdict:
+                out.add(ident)
+        return out
 
 
 def _topk_select(refs_lists: list[list[DocRef]],

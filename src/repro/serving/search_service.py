@@ -360,7 +360,17 @@ class SearchService:
         `batched=False` is the serial per-query loop, kept for
         comparison benchmarks. Results are identical either way; only
         latency and request count differ.
+
+        `impl` picks how candidates combine: "sorted" (NumPy set ops),
+        "bitmap" (the Pallas kernels, per index unit) or, for a
+        `ShardedIndex`, "fused": the cluster-fused gather — round 1 on
+        every shard, ONE `combine_cluster` launch over every (shard,
+        query), the global Eq. 6 budget and the canonical-order
+        completion (`ClusterSearcher.query_batch(fused=True)`).
         """
+        if impl == "fused" and not isinstance(self._index, ShardedIndex):
+            raise ValueError("impl='fused' is the cluster-fused gather of "
+                             "a ShardedIndex; this service serves an Index")
         if not batched:
             return [self.search(q, top_k=top_k) for q in queries]
         with spans.span("service.search_batch") as s:
@@ -371,6 +381,10 @@ class SearchService:
             assign: list[tuple[int, int]] = []   # (result slot, to_fetch idx)
             for i, q in enumerate(qs):
                 key = self._cache_key(q, top_k)
+                if impl == "fused":
+                    # the fused gather's top-K is the first K of its own
+                    # canonical order, not the per-shard merge's
+                    key += ("fused",)
                 hit = self._cache_get(key)
                 if hit is not None:
                     results[i] = hit

@@ -32,7 +32,7 @@ from repro.kernels.intersect import (OP_AND, OP_ANDNOT, OP_OR,
                                      intersect_batch_ref, intersect_ref,
                                      interpret_mode,
                                      pack_cluster_programs, pack_programs,
-                                     postings_to_bitmap,
+                                     pad_to_shape_class, postings_to_bitmap,
                                      postings_to_bitmap_batch)
 
 W_1M = (1_000_000 + 31) // 32          # words of a 1M-document universe
@@ -241,6 +241,35 @@ def test_cluster_interpret_vs_ref(G, Q, L, n_docs):
     assert cnt_p.shape == (G, Q)
     assert (np.asarray(out_p) == np.asarray(out_r)).all()
     assert (np.asarray(cnt_p) == np.asarray(cnt_r)).all()
+
+
+@pytest.mark.parametrize("G,Q,L,n_docs", [(0, 3, 3, 5000), (0, 1, 1, 31),
+                                          (3, 5, 3, 2000), (2, 4, 4, 40_000)])
+def test_shape_class_padding_keeps_results(G, Q, L, n_docs):
+    """A batch padded to its shape class reads, in its first Q rows cut
+    to W words, what it reads unpadded; G = 0 is a `combine_batch` one."""
+    rng = np.random.default_rng(G * 7 + Q * 3 + L)
+    groups = max(G, 1)
+    bm = np.stack([postings_to_bitmap_batch(
+        [_random_postings(rng, L, n_docs) for _ in range(Q)], n_docs)
+        for _ in range(groups)])
+    packed = pack_cluster_programs(
+        [_random_programs(rng, Q, L) for _ in range(groups)], L)
+    x, p = pad_to_shape_class(bm, packed)
+    *_, Qc, Lc, Wc = x.shape
+    assert (Qc, Lc, p.shape[-2]) == tuple(
+        1 << max(n - 1, 0).bit_length() for n in (Q, L, packed.shape[-2]))
+    assert Wc >= 1024 and Wc & (Wc - 1) == 0
+    if G:
+        got, cnt = combine_cluster_ref(x, p)
+        want, want_cnt = combine_cluster_ref(bm, packed)
+    else:
+        got, cnt = combine_batch_ref(x[0], p[0])
+        want, want_cnt = combine_batch_ref(bm[0], packed[0])
+    W = bm.shape[-1]
+    np.testing.assert_array_equal(np.asarray(got)[..., :Q, :W], want)
+    np.testing.assert_array_equal(np.asarray(cnt)[..., :Q], want_cnt)
+    assert not np.asarray(got)[..., Q:, :].any()
 
 
 def test_cluster_counts_match_per_shard_popcounts():
