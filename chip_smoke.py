@@ -34,6 +34,7 @@ benchmark.
 from __future__ import annotations
 
 import argparse
+import functools
 import json
 import os
 import shutil
@@ -55,6 +56,7 @@ from repro.data.tokenizer import parse_words  # noqa: E402
 from repro.index import (And, BuilderConfig, Index, Not, Or,  # noqa: E402
                          Phrase, Regex, Term)
 from repro.kernels import intersect as ki  # noqa: E402
+from repro.kernels.intersect import kernel as kk  # noqa: E402
 from repro.serving import (Frontend, FrontendConfig,  # noqa: E402
                            SearchService, ShardedIndex)
 from repro.storage import BlobStoreTransport, LocalBlobStore  # noqa: E402
@@ -120,9 +122,26 @@ def _random_programs(rng, n: int, n_layers: int) -> np.ndarray:
     return ki.pack_programs(progs, n_layers)
 
 
+def _launch_specs(args) -> list[jax.ShapeDtypeStruct]:
+    """The operand shapes a batch op launches its kernel at: its batch
+    padded to the `launch_class` of a 1M-document unit."""
+    bm, *progs = args
+    *lead, Q, L, W = bm.shape
+    c = ki.launch_class(Q, L, W, progs[0].shape[-2] if progs else None,
+                        n_docs=N_DOCS)
+    specs = [jax.ShapeDtypeStruct((*lead, c["Qc"], c["Lc"], c["Wc"]),
+                                  jnp.uint32)]
+    if progs:
+        specs.append(jax.ShapeDtypeStruct((*lead, c["Qc"], c["Sc"], 3),
+                                          jnp.int32))
+    return specs
+
+
 def check_kernels(seed: int) -> None:
     """Compile each intersect op on the chip at a 1M-document width and
-    compare it bit-exact with its jnp reference."""
+    compare it bit-exact with its jnp reference. The batch ops pad on
+    the host, so their kernel is compiled at the shape they launch and
+    the op itself is run."""
     if ki.interpret_mode():
         fail("intersect kernels would run interpreted on this backend")
     rng = np.random.default_rng(seed)
@@ -132,16 +151,25 @@ def check_kernels(seed: int) -> None:
     cl_bm = _random_bitmaps(rng, (G, 8, 4, W))
     cl_progs = _random_programs(rng, G * 8, 4).reshape(G, 8, -1, 3)
     cases = {
-        "intersect": (ki.intersect, (bm[0],)),
-        "intersect_batch": (ki.intersect_batch, (bm,)),
-        "combine_batch": (ki.combine_batch, (bm, progs)),
-        "combine_cluster": (ki.combine_cluster, (cl_bm, cl_progs)),
+        "intersect": (ki.intersect, None, (bm[0],)),
+        "intersect_batch": (ki.intersect_batch,
+                            kk.intersect_batch_pallas, (bm,)),
+        "combine_batch": (ki.combine_batch, kk.combine_batch_pallas,
+                          (bm, progs)),
+        "combine_cluster": (ki.combine_cluster, kk.combine_cluster_pallas,
+                            (cl_bm, cl_progs)),
     }
-    for name, (op, args) in cases.items():
-        compiled = jax.jit(op).lower(*args).compile()
+    for name, (op, entry, args) in cases.items():
+        if entry is None:
+            compiled = jax.jit(op).lower(*args).compile()
+            got = compiled(*args)
+            want = op(*args, impl="ref")
+        else:
+            compiled = jax.jit(functools.partial(entry, interpret=False)) \
+                .lower(*_launch_specs(args)).compile()
+            got = op(*args, n_docs=N_DOCS)
+            want = op(*args, impl="ref", n_docs=N_DOCS)
         mosaic = "tpu_custom_call" in compiled.as_text()
-        got = compiled(*args)
-        want = op(*args, impl="ref")
         exact = all(bool(jnp.array_equal(g, w)) for g, w in zip(got, want))
         log(f"kernel {name}: shapes={[a.shape for a in args]} "
             f"tpu_custom_call={mosaic} bit_exact_vs_ref={exact}")

@@ -506,90 +506,130 @@ def _recover_lengths(keys: np.ndarray,
     return lengths
 
 
+Universe = tuple[np.ndarray, np.ndarray]     # sorted keys, their lengths
+
+
+def _universe(leaves: list[tuple[np.ndarray, np.ndarray]],
+              ) -> tuple[Universe | None, list[np.ndarray]]:
+    """A job's universe — the sorted union of its leaves' keys, with
+    each key's length; None when that is empty — and each leaf's
+    positions in it, from one stable sort of the leaves' concatenated
+    (each sorted) keys."""
+    if not leaves:
+        return None, []
+    cat = np.concatenate([k for k, _l in leaves])
+    if not len(cat):
+        return None, []
+    order = np.argsort(cat, kind="stable")
+    ranked = cat[order]
+    new = np.empty(len(ranked), np.bool_)
+    new[0] = True
+    np.not_equal(ranked[1:], ranked[:-1], out=new[1:])
+    pos = np.empty(len(cat), np.uint32)
+    pos[order] = np.cumsum(new, dtype=np.uint32) - 1
+    keys = ranked[new].astype(np.uint64, copy=False)
+    lengths = np.empty(len(keys), np.uint64)
+    lengths[pos] = np.concatenate([l for _k, l in leaves])
+    return (keys, lengths), np.split(
+        pos, np.cumsum([len(k) for k, _l in leaves])[:-1])
+
+
+def _selected(universe: Universe, row: np.ndarray,
+              ) -> tuple[np.ndarray, np.ndarray]:
+    """The keys of `universe` that a kernel's result row selects, with
+    their lengths."""
+    from ..kernels.intersect import bitmap_to_docs
+
+    keys, lengths = universe
+    sel = bitmap_to_docs(row, len(keys))
+    return keys[sel], lengths[sel]
+
+
+def _launch_operands(rows: list[list[np.ndarray]],
+                     programs: list[list[tuple[int, int, int]]],
+                     n_bits: int) -> tuple[np.ndarray, np.ndarray]:
+    """One launch's operands for the batch's own shape: bitmaps (n, L, W)
+    of each job's leaf positions, zero-padded to the batch's L (at least
+    one) layers — a job with none reads its zero layer 0 as the empty
+    set — and (n, S, 3) programs, their step slots moved up past each
+    job's added layers. A job with no layers keeps its program as it
+    is: its slots name layer 0, not steps."""
+    from ..kernels.intersect import pack_programs, postings_to_bitmap_batch
+
+    bitmaps = postings_to_bitmap_batch(rows, n_bits, fill=0)
+    if not bitmaps.shape[1]:
+        bitmaps = np.zeros((len(rows), 1, bitmaps.shape[2]), np.uint32)
+    L = bitmaps.shape[1]
+    padded = []
+    for posts, steps in zip(rows, programs):
+        n = len(posts)
+        shift = L - n if n else 0
+        padded.append([(op, a + shift if a >= n else a,
+                        b + shift if b >= n else b) for op, a, b in steps])
+    return bitmaps, pack_programs(padded, L)
+
+
 def combine_planned(plans: list[PhysicalPlan],
                     per_words: list[dict],
                     is_common: Callable[[str], bool],
-                    impl: str = "sorted",
+                    impl: str = "sorted", *,
+                    n_docs: int,
                     ) -> list[tuple[np.ndarray, np.ndarray]]:
     """Evaluate several planned queries' candidate algebra for one unit.
 
     `impl="sorted"` runs NumPy set ops per query; `impl="bitmap"` maps
     each query's leaf postings into a dense per-query universe and
     evaluates every compiled program in ONE batched Pallas
-    `combine_batch` call (AND/OR/ANDNOT fused per document tile).
+    `combine_batch` call (AND/OR/ANDNOT fused per document tile), whose
+    width class `n_docs`, the unit's document count, fixes.
     """
     if impl != "bitmap":
         return [_eval_steps(*_compile_steps(p, pw, is_common))
                 for p, pw in zip(plans, per_words)]
 
-    from ..kernels.intersect import (combine_batch, pack_programs,
-                                     postings_to_bitmap_batch)
+    from ..kernels.intersect import combine_batch, launch_class
 
     with spans.span("combine.build") as s:
         compiled = [_compile_steps(p, pw, is_common)
                     for p, pw in zip(plans, per_words)]
-        universes: list[np.ndarray | None] = []
+        universes: list[Universe | None] = []
         rows: list[list[np.ndarray]] = []
         programs: list[list[tuple[int, int, int]]] = []
         out: list[tuple[np.ndarray, np.ndarray] | None] = [None] * len(plans)
         for j, (leaves, steps) in enumerate(compiled):
-            keys_list = [k for k, _l in leaves]
-            uni = np.unique(np.concatenate(keys_list)) if keys_list else \
-                np.empty(0, np.uint64)
-            if not len(uni):
-                universes.append(None)
+            uni, posts = _universe(leaves)
+            universes.append(uni)
+            if uni is None:
                 out[j] = (np.empty(0, np.uint64), np.empty(0, np.uint64))
                 continue
-            universes.append(uni)
-            rows.append([np.searchsorted(uni, k).astype(np.uint32)
-                         for k in keys_list])
+            rows.append(posts)
             programs.append(steps)
         if rows:
-            n_bits = max(len(u) for u in universes if u is not None)
-            L_max = max(len(r) for r in rows)
-            # ragged padding: unused layers are all-zero (never
-            # referenced — programs only touch their own leaves)
-            W = (n_bits + 31) // 32
-            bitmaps = np.zeros((len(rows), L_max, W), dtype=np.uint32)
-            for q, posts in enumerate(rows):
-                bitmaps[q, :len(posts)] = postings_to_bitmap_batch(
-                    [posts], n_bits)[0, :len(posts)]
-            # re-point step slots at the padded layer count
-            padded = []
-            for posts, steps in zip(rows, programs):
-                shift = L_max - len(posts)
-                padded.append([(op,
-                                a + shift if a >= len(posts) else a,
-                                b + shift if b >= len(posts) else b)
-                               for op, a, b in steps])
-            progs = pack_programs(padded, L_max)
-            shape = dict(zip("QLW", bitmaps.shape))
-            s.set(kernel="combine_batch", **shape)
+            n_bits = max(len(u[0]) for u in universes if u is not None)
+            bitmaps, progs = _launch_operands(rows, programs, n_bits)
+            shape = dict(kernel="combine_batch",
+                         **dict(zip("QLW", bitmaps.shape)), S=progs.shape[1])
+            shape.update(launch_class(*bitmaps.shape, progs.shape[1],
+                                      n_docs=n_docs))
+            s.set(**shape)
     if not rows:
         return out  # type: ignore[return-value]
 
     with spans.span("combine.call") as s:
-        s.set(kernel="combine_batch", **shape)
-        inter, _counts = combine_batch(bitmaps, progs)
-        inter = np.asarray(inter)
+        s.set(**shape)
+        inter, _counts = combine_batch(bitmaps, progs, n_docs=n_docs)
     with spans.span("combine.unpack"):
-        row_i = 0
-        for j, (leaves, _steps) in enumerate(compiled):
-            if universes[j] is None:
-                continue
-            uni = universes[j]
-            bits = np.unpackbits(inter[row_i].view(np.uint8),
-                                 bitorder="little")
-            sel = np.flatnonzero(bits[:len(uni)])
-            row_i += 1
-            keys = uni[sel].astype(np.uint64, copy=False)
-            out[j] = (keys, _recover_lengths(keys, leaves))
+        rows_of = iter(inter)
+        for j, uni in enumerate(universes):
+            if uni is not None:
+                out[j] = _selected(uni, next(rows_of))
     return out  # type: ignore[return-value]
 
 
 def combine_cluster_planned(plans_by_group: list[list[PhysicalPlan]],
                             per_words_by_group: list[list[dict]],
                             is_common_by_group: list[Callable[[str], bool]],
+                            *, n_docs: int,
                             ) -> tuple[list[list[tuple[np.ndarray,
                                                        np.ndarray]]],
                                        np.ndarray]:
@@ -600,99 +640,60 @@ def combine_cluster_planned(plans_by_group: list[list[PhysicalPlan]],
     `per_words_by_group[g][q]`, and `is_common_by_group[g]` follow
     `combine_planned`'s bitmap path per group, but instead of one
     `combine_batch` launch per unit the whole cluster's programs run on
-    a single (shard, query, tile) grid. Returns `(results, counts)`:
+    a single (shard, query, tile) grid, whose width class `n_docs`, the
+    largest unit's document count, fixes. Returns `(results, counts)`:
     `results[g][q]` is the sorted `(keys, lengths)` candidate pair and
     `counts` a (G, Q) int64 array of per-(group, query) candidate
     totals — exactly the round-1 statistics `shard_quotas` consumes.
     """
-    from ..kernels.intersect import (combine_cluster, pack_cluster_programs,
-                                     pad_to_shape_class,
-                                     postings_to_bitmap_batch)
+    from ..kernels.intersect import combine_cluster, launch_class
 
     G = len(plans_by_group)
     Q = len(plans_by_group[0]) if G else 0
     if not G or not Q:
         return [[] for _ in range(G)], np.zeros((G, Q), dtype=np.int64)
     with spans.span("combine.build") as s:
-        compiled = [[_compile_steps(plans_by_group[g][q],
-                                    per_words_by_group[g][q],
-                                    is_common_by_group[g])
-                     for q in range(Q)] for g in range(G)]
-        universes: list[list[np.ndarray | None]] = \
-            [[None] * Q for _ in range(G)]
-        rows: list[list[list[np.ndarray]]] = [[[] for _ in range(Q)]
-                                              for _ in range(G)]
-        programs: list[list[list[tuple[int, int, int]]]] = \
-            [[[] for _ in range(Q)] for _ in range(G)]
-        for g in range(G):
-            for q in range(Q):
-                leaves, steps = compiled[g][q]
-                keys_list = [k for k, _l in leaves]
-                uni = np.unique(np.concatenate(keys_list)) if keys_list else \
-                    np.empty(0, np.uint64)
-                if not len(uni):
-                    # placeholder block: layer 0 of the zero-filled tensor is
-                    # all-zero, so AND(0, 0) evaluates to the empty set the
-                    # grid still needs a program for
-                    programs[g][q] = [(OP_AND, 0, 0)]
-                    continue
-                universes[g][q] = uni
-                rows[g][q] = [np.searchsorted(uni, k).astype(np.uint32)
-                              for k in keys_list]
-                programs[g][q] = steps
-        n_bits = max((len(u) for row in universes for u in row
-                      if u is not None), default=1)
-        L_max = max(max((len(r) for r in row), default=0)
-                    for row in rows) or 1
-        W = (n_bits + 31) // 32
-        bitmaps = np.zeros((G, Q, L_max, W), dtype=np.uint32)
-        padded: list[list[list[tuple[int, int, int]]]] = \
-            [[[] for _ in range(Q)] for _ in range(G)]
-        for g in range(G):
-            for q in range(Q):
-                posts = rows[g][q]
-                if posts:
-                    bitmaps[g, q, :len(posts)] = postings_to_bitmap_batch(
-                        [posts], n_bits)[0, :len(posts)]
-                    # re-point step slots at the padded layer count
-                    shift = L_max - len(posts)
-                    padded[g][q] = [(op,
-                                     a + shift if a >= len(posts) else a,
-                                     b + shift if b >= len(posts) else b)
-                                    for op, a, b in programs[g][q]]
-                else:
-                    padded[g][q] = programs[g][q]      # zero-layer identity
-        progs = pack_cluster_programs(padded, L_max)
-        # the work the batch asks for, before its shape class pads it
-        shape = dict(kernel="combine_cluster", G=G, Q=Q, L=L_max, W=W,
+        # (shard, query) pairs flattened shard-major, as the kernel's
+        # query axis takes them
+        compiled = [_compile_steps(plans_by_group[g][q],
+                                   per_words_by_group[g][q],
+                                   is_common_by_group[g])
+                    for g in range(G) for q in range(Q)]
+        universes: list[Universe | None] = []
+        rows: list[list[np.ndarray]] = []
+        programs: list[list[tuple[int, int, int]]] = []
+        for leaves, steps in compiled:
+            uni, posts = _universe(leaves)
+            universes.append(uni)
+            rows.append(posts)
+            # an empty universe still needs a program on the grid:
+            # AND(0, 0) of its zero layer 0 is the empty set
+            programs.append(steps if uni is not None else [(OP_AND, 0, 0)])
+        n_bits = max((len(u[0]) for u in universes if u is not None),
+                     default=1)
+        bitmaps, progs = _launch_operands(rows, programs, n_bits)
+        bitmaps = bitmaps.reshape(G, Q, *bitmaps.shape[1:])
+        progs = progs.reshape(G, Q, *progs.shape[1:])
+        # the work the batch asks for, and the class it launches at
+        shape = dict(kernel="combine_cluster", G=G, Q=Q,
+                     L=bitmaps.shape[2], W=bitmaps.shape[3],
                      S=progs.shape[2])
+        shape.update(launch_class(Q, shape["L"], shape["W"], shape["S"],
+                                  n_docs=n_docs))
         s.set(**shape)
-        bitmaps, progs = pad_to_shape_class(bitmaps, progs)
     with spans.span("combine.call") as s:
         s.set(**shape)
-        inter, counts = combine_cluster(bitmaps, progs)
-        # the chip may hand the (G, Q, W) result back in a layout other
-        # than C order (W outermost when W is a few words), where a
-        # row's words are not adjacent and `.view` cannot reinterpret
-        # them: copy it to C order, whose values are the same
-        inter = np.ascontiguousarray(np.asarray(inter)[:, :Q, :W])
-        counts = np.asarray(counts)[:, :Q].astype(np.int64)
+        inter, counts = combine_cluster(bitmaps, progs, n_docs=n_docs)
+        counts = counts.astype(np.int64)
     with spans.span("combine.unpack") as s:
         s.set(**shape)
         results: list[list[tuple[np.ndarray, np.ndarray]]] = \
             [[(np.empty(0, np.uint64), np.empty(0, np.uint64))] * Q
              for _ in range(G)]
-        for g in range(G):
-            for q in range(Q):
-                uni = universes[g][q]
-                if uni is None:
-                    continue
-                bits = np.unpackbits(inter[g, q].view(np.uint8),
-                                     bitorder="little")
-                sel = np.flatnonzero(bits[:len(uni)])
-                keys = uni[sel].astype(np.uint64, copy=False)
-                leaves, _steps = compiled[g][q]
-                results[g][q] = (keys, _recover_lengths(keys, leaves))
+        for i, uni in enumerate(universes):
+            if uni is not None:
+                g, q = divmod(i, Q)
+                results[g][q] = _selected(uni, inter[g, q])
     return results, counts
 
 

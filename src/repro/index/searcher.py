@@ -67,7 +67,8 @@ from ..storage.transport import (SimCloudTransport, StorageTransport,
 from . import codec
 from .fetch_plan import coalesce_requests, slice_payloads
 from .planner import (DocContent, Job as _Job,
-                      _classic_matches as _matches, combine_planned,
+                      _classic_matches as _matches, _selected,
+                      _universe, combine_planned,
                       make_job, plan_batch, regex_prefilter)
 from .query import And, Or, Query, Regex, Term, query_words
 
@@ -281,6 +282,9 @@ class Searcher:
         raw_ngrams = self.profile.get("index_ngrams")
         self.ngram_n: int | None = \
             None if raw_ngrams is None else int(raw_ngrams)
+        # documents in this unit: no job's candidate universe holds more,
+        # so the combine kernels take their launch width from it
+        self.n_docs = int(self.profile["n_docs"])
 
     # fetch knobs live in ONE place — the _Fetcher every round goes
     # through — so post-construction mutation keeps taking effect
@@ -957,14 +961,15 @@ def _combine_jobs(jobs: list[_Job],
         parts_list = [[per_word_list[j][w]
                        for w in query_words(jobs[j].lookup_q)]
                       for j in bitmap_jobs]
-        for j, res in zip(bitmap_jobs, _bitmap_and_batch(parts_list)):
+        for j, res in zip(bitmap_jobs,
+                          _bitmap_and_batch(parts_list, unit.n_docs)):
             out[j] = res
     if planned_jobs:
         is_common = lambda w: word_fingerprint(w) in unit.common  # noqa: E731
         results = combine_planned(
             [jobs[j].plan for j in planned_jobs],
             [per_word_list[j] for j in planned_jobs],
-            is_common, impl=impl)
+            is_common, impl=impl, n_docs=unit.n_docs)
         for j, res in zip(planned_jobs, results):
             out[j] = res
     return out  # type: ignore[return-value]
@@ -995,56 +1000,43 @@ def _combine(q: Query, per_word: dict[str, tuple[np.ndarray, np.ndarray]],
 
 
 def _bitmap_and_batch(parts_list: list[list[tuple[np.ndarray, np.ndarray]]],
+                      n_docs: int,
                       ) -> list[tuple[np.ndarray, np.ndarray]]:
     """Batched multi-way AND via the Pallas bitmap kernel.
 
     Each job's posting keys are mapped into a dense per-job universe
     (the union of its words' candidate keys); all jobs' bitsets are then
-    intersected in ONE `intersect_batch` call, ragged L and W padded to
-    the batch maxima (all-ones layers are AND identities; key universes
-    shorter than the widest job simply leave their tail bits zero).
+    intersected in ONE `intersect_batch` call, ragged L padded with
+    all-ones layers (the AND identity) and the launch's width class
+    fixed by `n_docs`, the unit's document count.
     """
-    from ..kernels.intersect import intersect_batch, postings_to_bitmap_batch
+    from ..kernels.intersect import (intersect_batch, launch_class,
+                                     postings_to_bitmap_batch)
 
     with spans.span("combine.build") as s:
-        universes: list[np.ndarray | None] = []
+        universes = []
         rows: list[list[np.ndarray]] = []
         for parts in parts_list:
-            keys_list = [k for k, _l in parts]
-            if any(len(k) == 0 for k in keys_list):
+            if any(len(k) == 0 for k, _l in parts):
                 universes.append(None)      # empty AND — no kernel work
                 continue
-            uni = np.unique(np.concatenate(keys_list))
+            uni, posts = _universe(parts)
             universes.append(uni)
-            rows.append([np.searchsorted(uni, k).astype(np.uint32)
-                         for k in keys_list])
+            rows.append(posts)
         if rows:
-            n_bits = max(len(u) for u in universes if u is not None)
+            n_bits = max(len(u[0]) for u in universes if u is not None)
             bitmaps = postings_to_bitmap_batch(rows, n_bits)
-            shape = dict(zip("QLW", bitmaps.shape))
-            s.set(kernel="intersect_batch", **shape)
+            shape = dict(kernel="intersect_batch",
+                         **dict(zip("QLW", bitmaps.shape)))
+            shape.update(launch_class(*bitmaps.shape, n_docs=n_docs))
+            s.set(**shape)
 
     if rows:
         with spans.span("combine.call") as s:
-            s.set(kernel="intersect_batch", **shape)
-            inter, _counts = intersect_batch(bitmaps)
-            inter = np.asarray(inter)
+            s.set(**shape)
+            inter, _counts = intersect_batch(bitmaps, n_docs=n_docs)
     with spans.span("combine.unpack"):
-        out: list[tuple[np.ndarray, np.ndarray]] = []
-        row_i = 0
-        for parts, uni in zip(parts_list, universes):
-            if uni is None:
-                out.append((np.empty(0, dtype=np.uint64),
-                            np.empty(0, dtype=np.uint64)))
-                continue
-            bits = np.unpackbits(inter[row_i].view(np.uint8),
-                                 bitorder="little")
-            sel = np.flatnonzero(bits[:len(uni)])
-            row_i += 1
-            keys = uni[sel]
-            k0, l0 = parts[0]
-            lengths = l0[np.searchsorted(k0, keys)]
-            out.append((keys, lengths))
-    return out
-
-
+        empty = (np.empty(0, dtype=np.uint64), np.empty(0, dtype=np.uint64))
+        rows_of = iter(inter) if rows else iter(())
+        return [empty if uni is None else _selected(uni, next(rows_of))
+                for uni in universes]

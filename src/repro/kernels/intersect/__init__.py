@@ -1,16 +1,16 @@
 from .ops import (OP_AND, OP_ANDNOT, OP_OR, UnsupportedBackendError,
-                  bitmap_to_docs, combine_batch, combine_cluster,
-                  interpret_mode, intersect, intersect_batch,
-                  pack_cluster_programs, pack_programs, pad_to_shape_class,
-                  postings_to_bitmap, postings_to_bitmap_batch)
+                  bitmap_to_docs, combine_batch, combine_cluster, compiles,
+                  interpret_mode, intersect, intersect_batch, launch_class,
+                  pack_cluster_programs, pack_programs, postings_to_bitmap,
+                  postings_to_bitmap_batch, unit_words, warm_shape_classes)
 from .ref import (combine_batch_ref, combine_cluster_ref, intersect_batch_ref,
                   intersect_ref, popcount)
 
 __all__ = ["OP_AND", "OP_ANDNOT", "OP_OR", "UnsupportedBackendError",
-           "bitmap_to_docs", "combine_batch", "combine_cluster",
+           "bitmap_to_docs", "combine_batch", "combine_cluster", "compiles",
            "interpret_mode", "intersect",
-           "intersect_batch", "pack_cluster_programs", "pack_programs",
-           "pad_to_shape_class", "postings_to_bitmap",
-           "postings_to_bitmap_batch", "combine_batch_ref",
-           "combine_cluster_ref", "intersect_batch_ref", "intersect_ref",
-           "popcount"]
+           "intersect_batch", "launch_class", "pack_cluster_programs",
+           "pack_programs", "postings_to_bitmap",
+           "postings_to_bitmap_batch", "unit_words", "warm_shape_classes",
+           "combine_batch_ref", "combine_cluster_ref", "intersect_batch_ref",
+           "intersect_ref", "popcount"]

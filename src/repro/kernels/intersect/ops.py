@@ -5,6 +5,14 @@ decided here and nowhere else, from the default backend: compiled
 (Mosaic) on a TPU, interpreted on the CPU (where the tests run), and an
 `UnsupportedBackendError` anywhere else. `impl="ref"` selects the
 pure-jnp oracle explicitly; no backend falls back to it.
+
+The shape a kernel compiles at is decided here too, and nowhere else:
+`intersect_batch`, `combine_batch` and `combine_cluster` pad every batch
+to its `launch_class` — Q a power of two, L and S powers of two of at
+least 4, W the class of the index unit's document count — and cut the
+result back to the batch's own rows and words. A stream of batches then
+runs a few compiled programs, which `warm_shape_classes` can compile
+ahead of the first batch.
 """
 
 from __future__ import annotations
@@ -25,11 +33,17 @@ class UnsupportedBackendError(RuntimeError):
     intersect kernels."""
 
 
+def compiles() -> bool:
+    """True when the kernels run compiled (on a TPU), where each new
+    launch shape costs a compile."""
+    return jax.default_backend() == "tpu"
+
+
 def interpret_mode() -> bool:
     """True when the kernels must run in the Pallas interpreter."""
-    backend = jax.default_backend()
-    if backend == "tpu":
+    if compiles():
         return False
+    backend = jax.default_backend()
     if backend == "cpu":
         return True
     raise UnsupportedBackendError(
@@ -49,26 +63,45 @@ def postings_to_bitmap(postings: list[np.ndarray], n_docs: int) -> np.ndarray:
 
 
 def postings_to_bitmap_batch(postings_batch: list[list[np.ndarray]],
-                             n_docs: int) -> np.ndarray:
+                             n_docs: int, fill: int = 0xFFFFFFFF,
+                             ) -> np.ndarray:
     """Ragged batch of doc-id lists → (Q, L_max, W) uint32 bitsets.
 
-    Queries with fewer than L_max postings lists are padded with all-ones
-    layers — the AND identity — so one fused kernel call handles a batch
-    of queries with different term counts.
+    Queries with fewer than L_max postings lists are padded with `fill`
+    layers: all-ones by default — the AND identity — so one fused kernel
+    call handles a batch of queries with different term counts. The
+    combine kernels' programs never read a query's added layers, and
+    pass `fill=0`, so that a query with no layers reads as empty. Each
+    layer is packed from a flag per document up to its query's largest
+    id, so a query of a few ids costs a few words whatever W is.
     """
-    L_max = max(len(p) for p in postings_batch)
+    L_max = max((len(p) for p in postings_batch), default=0)
     W = (n_docs + 31) // 32
-    out = np.full((len(postings_batch), L_max, W), 0xFFFFFFFF,
-                  dtype=np.uint32)
+    out = np.zeros((len(postings_batch), L_max, W), dtype=np.uint32)
     for q, posts in enumerate(postings_batch):
-        out[q, :len(posts)] = postings_to_bitmap(posts, n_docs)
+        out[q, len(posts):] = fill
+        top = max((int(np.max(d)) + 1 for d in posts if len(d)), default=0)
+        flags = np.zeros(-(-top // 32) * 32, np.bool_)
+        for l, docs in enumerate(posts):
+            flags[docs] = True
+            out[q, l, :len(flags) // 32] = np.packbits(
+                flags, bitorder="little").view(np.uint32)
+            flags[docs] = False
     return out
 
 
-def bitmap_to_docs(bitmap: np.ndarray) -> np.ndarray:
-    """Intersection bitset → sorted uint32 doc ids."""
-    bits = np.unpackbits(
-        np.asarray(bitmap, dtype=np.uint32).view(np.uint8), bitorder="little")
+def bitmap_to_docs(bitmap: np.ndarray, n_bits: int | None = None,
+                   ) -> np.ndarray:
+    """Intersection bitset → sorted uint32 doc ids, below `n_bits` when
+    given: only the words that hold them are unpacked, so a row of a
+    few dozen keys in a wide launch costs a few words, not the row."""
+    bitmap = np.asarray(bitmap, dtype=np.uint32)
+    if n_bits is not None:
+        bitmap = bitmap[:(n_bits + 31) // 32]
+    bits = np.unpackbits(np.ascontiguousarray(bitmap).view(np.uint8),
+                         bitorder="little")
+    if n_bits is not None:
+        bits = bits[:n_bits]
     return np.flatnonzero(bits).astype(np.uint32)
 
 
@@ -78,14 +111,6 @@ def intersect(bitmaps, impl: str = "pallas"):
     if impl == "ref":
         return intersect_ref(bitmaps)
     return intersect_pallas(bitmaps, interpret=interpret_mode())
-
-
-def intersect_batch(bitmaps, impl: str = "pallas"):
-    """(Q, L, W) uint32 → (bitmaps (Q, W), counts (Q,)). impl: pallas | ref."""
-    bitmaps = jnp.asarray(bitmaps, dtype=jnp.uint32)
-    if impl == "ref":
-        return intersect_batch_ref(bitmaps)
-    return intersect_batch_pallas(bitmaps, interpret=interpret_mode())
 
 
 def pack_programs(programs: list[list[tuple[int, int, int]]],
@@ -129,6 +154,7 @@ def pack_cluster_programs(programs: list[list[list[tuple[int, int, int]]]],
 
 
 W_CLASS_MIN = 1024     # words: the narrowest bitmap width class
+LS_CLASS_MIN = 4       # layers and program steps: the narrowest classes
 
 
 def shape_class(n: int, least: int = 1) -> int:
@@ -136,25 +162,53 @@ def shape_class(n: int, least: int = 1) -> int:
     return max(least, 1 << max(n - 1, 0).bit_length())
 
 
-def pad_to_shape_class(bitmaps: np.ndarray, programs: np.ndarray
-                       ) -> tuple[np.ndarray, np.ndarray]:
-    """Pad a combine batch to its shape class, so that a stream of
-    batches of many shapes compiles a few kernels, not one per shape.
+def unit_words(n_docs: int) -> int:
+    """The bitmap width class of an index unit of `n_docs` documents.
+
+    A job's universe is the union of its candidate keys in one unit, so
+    it never holds more keys than the unit has documents: every launch
+    over the unit fits this one width."""
+    return shape_class((n_docs + 31) // 32, W_CLASS_MIN)
+
+
+def launch_class(Q: int, L: int, W: int, S: int | None = None, *,
+                 n_docs: int) -> dict[str, int]:
+    """The shape a launch of a batch of Q queries, L layers, W words and
+    S program steps compiles at: Q a power of two; L and S powers of two
+    of at least `LS_CLASS_MIN`; W the class of the unit's `n_docs`
+    documents, or of W itself should a batch be wider than its unit.
+    Returns `Qc`, `Lc`, `Wc` and, for a program batch, `Sc`."""
+    out = dict(Qc=shape_class(Q), Lc=shape_class(L, LS_CLASS_MIN),
+               Wc=max(unit_words(n_docs), shape_class(W, W_CLASS_MIN)))
+    if S is not None:
+        out["Sc"] = shape_class(S, LS_CLASS_MIN)
+    return out
+
+
+def pad_to_shape_class(bitmaps: np.ndarray, programs: np.ndarray | None,
+                       n_docs: int,
+                       ) -> tuple[np.ndarray, np.ndarray | None]:
+    """Pad a batch to its `launch_class`, so that a stream of batches of
+    many shapes runs a few compiled kernels, not one per shape.
 
     bitmaps (..., Q, L, W) uint32 and programs (..., Q, S, 3) int32, as
-    `combine_batch` and `combine_cluster` take them. Q, L and S go up to
-    a power of two, W to one of at least `W_CLASS_MIN` words (as far as
-    the kernel's own tiles pad a narrower one). Added queries, layers
-    and words are zero; added steps chain the last result through
-    (AND(r, r)), and step slots move up past the added layers. The
-    result's first Q rows, cut to W words, and their counts are the
-    unpadded batch's.
+    `combine_batch` and `combine_cluster` take them; `programs=None` is
+    an `intersect_batch` batch, whose added layers are all-ones (the AND
+    identity). Otherwise added queries, layers and words are zero, added
+    steps chain the last result through (AND(r, r)), and step slots move
+    up past the added layers. The result's first Q rows, cut to W words,
+    and their counts are the unpadded batch's.
     """
     *lead, Q, L, W = bitmaps.shape
-    S = programs.shape[-2]
-    Qc, Lc, Sc = shape_class(Q), shape_class(L), shape_class(S)
-    x = np.zeros((*lead, Qc, Lc, shape_class(W, W_CLASS_MIN)), np.uint32)
+    S = None if programs is None else np.shape(programs)[-2]
+    c = launch_class(Q, L, W, S, n_docs=n_docs)
+    Qc, Lc = c["Qc"], c["Lc"]
+    x = np.zeros((*lead, Qc, Lc, c["Wc"]), np.uint32)
     x[..., :Q, :L, :W] = bitmaps
+    if programs is None:
+        x[..., :Q, L:, :] = 0xFFFFFFFF
+        return x, None
+    Sc = c["Sc"]
     chain = Lc + np.arange(Sc) - 1
     chain[0] = 0                       # an added query: AND(layer 0, 0)
     p = np.empty((*lead, Qc, Sc, 3), np.int32)
@@ -167,33 +221,80 @@ def pad_to_shape_class(bitmaps: np.ndarray, programs: np.ndarray
     return x, p
 
 
-def combine_batch(bitmaps, programs, impl: str = "pallas"):
+def _launch(kernel, bitmaps, programs, n_docs: int,
+            ) -> tuple[np.ndarray, np.ndarray]:
+    """Run `kernel` on the batch padded to its class; the result cut
+    back to the batch's own Q rows and W words, in C order (the chip
+    may hand a result back in another layout)."""
+    interpret = interpret_mode()
+    bitmaps = np.asarray(bitmaps, dtype=np.uint32)
+    *_lead, Q, _L, W = bitmaps.shape
+    x, p = pad_to_shape_class(bitmaps, programs, n_docs)
+    out, counts = kernel(*(x,) if p is None else (x, p),
+                         interpret=interpret)
+    return (np.ascontiguousarray(np.asarray(out)[..., :Q, :W]),
+            np.asarray(counts)[..., :Q])
+
+
+def intersect_batch(bitmaps, impl: str = "pallas", *, n_docs: int):
+    """(Q, L, W) uint32 → (bitmaps (Q, W), counts (Q,)). impl: pallas | ref.
+
+    `n_docs` is the document count of the index unit the batch's keys
+    come from, which fixes the launch's width class (`launch_class`)."""
+    if impl == "ref":
+        return intersect_batch_ref(jnp.asarray(bitmaps, dtype=jnp.uint32))
+    return _launch(intersect_batch_pallas, bitmaps, None, n_docs)
+
+
+def combine_batch(bitmaps, programs, impl: str = "pallas", *,
+                  n_docs: int):
     """Evaluate per-query AND/OR/ANDNOT programs over layered bitsets.
 
     bitmaps: (Q, L, W) uint32; programs: (Q, S, 3) int32 (see
     `pack_programs`) → (result bitmaps (Q, W), counts (Q,)).
-    impl: pallas | ref.
+    impl: pallas | ref. `n_docs` as for `intersect_batch`.
     """
-    bitmaps = jnp.asarray(bitmaps, dtype=jnp.uint32)
     if impl == "ref":
-        return combine_batch_ref(bitmaps, programs)
-    return combine_batch_pallas(bitmaps, jnp.asarray(programs,
-                                                     dtype=jnp.int32),
-                                interpret=interpret_mode())
+        return combine_batch_ref(jnp.asarray(bitmaps, dtype=jnp.uint32),
+                                 programs)
+    return _launch(combine_batch_pallas, bitmaps, programs, n_docs)
 
 
-def combine_cluster(bitmaps, programs, impl: str = "pallas"):
+def combine_cluster(bitmaps, programs, impl: str = "pallas", *,
+                    n_docs: int):
     """Evaluate a whole cluster's combine round in one fused call.
 
     bitmaps: (G, Q, L, W) uint32 — axis 0 is the shard unit; programs:
     (G, Q, S, 3) int32 (`pack_programs` per shard, padded to a common
     S/L). Returns (result bitmaps (G, Q, W), counts (G, Q)) — the
     counts are the per-(shard, query) candidate totals that drive the
-    global top-K sampling budget. impl: pallas | ref.
+    global top-K sampling budget. impl: pallas | ref. `n_docs` is the
+    largest unit's document count.
     """
-    bitmaps = jnp.asarray(bitmaps, dtype=jnp.uint32)
     if impl == "ref":
-        return combine_cluster_ref(bitmaps, programs)
-    return combine_cluster_pallas(bitmaps, jnp.asarray(programs,
-                                                       dtype=jnp.int32),
-                                  interpret=interpret_mode())
+        return combine_cluster_ref(jnp.asarray(bitmaps, dtype=jnp.uint32),
+                                   programs)
+    return _launch(combine_cluster_pallas, bitmaps, programs, n_docs)
+
+
+def warm_shape_classes(n_docs: int, q_max: int, groups: int = 0,
+                       ) -> list[tuple[int, ...]]:
+    """Launch each kernel once on zeros at every Q class up to `q_max`,
+    at the L, S and W classes of a unit of `n_docs` documents, so that
+    the jit cache holds every program a batch of up to `q_max` jobs over
+    that unit asks for. `groups` > 0 warms the cluster-fused kernel over
+    that many shard units (`n_docs` the largest unit's) in place of the
+    per-unit kernels. Returns each launch's (G, Q) or (Q,) shape."""
+    launched = []
+    for Q in (1 << i for i in range(shape_class(q_max).bit_length())):
+        if groups:
+            combine_cluster(np.zeros((groups, Q, 1, 1), np.uint32),
+                            np.zeros((groups, Q, 1, 3), np.int32),
+                            n_docs=n_docs)
+            launched.append((groups, Q))
+            continue
+        intersect_batch(np.zeros((Q, 1, 1), np.uint32), n_docs=n_docs)
+        combine_batch(np.zeros((Q, 1, 1), np.uint32),
+                      np.zeros((Q, 1, 3), np.int32), n_docs=n_docs)
+        launched.append((Q,))
+    return launched

@@ -1637,7 +1637,8 @@ class ClusterSearcher:
                     common_by_group.append(
                         lambda w, u=unit: word_fingerprint(w) in u.common)
             combined, counts = combine_cluster_planned(
-                plans_by_group, words_by_group, common_by_group)
+                plans_by_group, words_by_group, common_by_group,
+                n_docs=max(unit.n_docs for _si, unit in groups))
             shard_candidates = [0] * n_shards
             for g, (si, _u) in enumerate(groups):
                 shard_candidates[si] += int(counts[g].sum())

@@ -169,6 +169,12 @@ class Frontend:
             raise TypeError(
                 f"{type(backend).__name__} exposes neither search_batch "
                 "nor query_batch")
+        # a backend that compiles its kernels per batch shape (a
+        # SearchService) compiles every shape this frontend's batches
+        # can take before the first one is served
+        warm = getattr(backend, "warm", None)
+        if warm is not None:
+            warm(self.config.max_batch)
 
     # -- admission --------------------------------------------------------
     @property

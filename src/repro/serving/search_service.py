@@ -33,13 +33,15 @@ import numpy as np
 
 from ..analysis import spans
 from ..compat import deprecated_call
-from ..index.lifecycle import Index
+from ..index.lifecycle import Index, MultiSegmentSearcher
 from ..index.query import Query, Regex, normalize, parse
 from ..index.searcher import Searcher
+from ..kernels.intersect import compiles, unit_words, warm_shape_classes
 from ..storage.cache import LRUCache, SuperpostCache
 from ..storage.simcloud import SimCloudStore
 from ..storage.transport import SimCloudTransport
-from .cluster import ShardedIndex
+from .cluster import ClusterSearcher, ShardedIndex
+from .frontend import FrontendConfig
 
 
 @dataclass
@@ -146,6 +148,32 @@ class LatencyStats:
         }
 
 
+def _units(reader: Searcher | MultiSegmentSearcher) -> list[Searcher]:
+    return reader.units if isinstance(reader, MultiSegmentSearcher) \
+        else [reader]
+
+
+def _warm_kernels(searcher, max_batch: int) -> None:
+    """Compile every launch class of the combine kernels that a batch of
+    up to `max_batch` queries over `searcher`'s index units can ask for,
+    so that none compiles while serving: the per-unit kernels at each
+    unit's width class and, for a cluster, also the fused kernel over
+    every shard unit at the widest one's, since a call may take either
+    gather."""
+    if isinstance(searcher, ClusterSearcher):
+        fused = [u for replicas in searcher.shard_replicas
+                 for u in _units(replicas[0].reader)]
+        warm_shape_classes(max(u.n_docs for u in fused), max_batch,
+                           groups=len(fused))
+        units = [u for replicas in searcher.shard_replicas
+                 for r in replicas for u in _units(r.reader)]
+    else:
+        units = _units(searcher)
+    widths = {unit_words(u.n_docs): u.n_docs for u in units}
+    for n_docs in widths.values():
+        warm_shape_classes(n_docs, max_batch)
+
+
 class SearchService:
     def __init__(self, source, index_prefix: str | None = None,
                  hedge: bool = False, cache_size: int = 0,
@@ -171,6 +199,9 @@ class SearchService:
         # write can never serve pre-commit results
         self._cache: LRUCache | None = \
             LRUCache(cache_size) if cache_size else None
+        # the largest batch whose kernel classes are compiled at open: a
+        # default frontend's until a `Frontend` names its own (`warm`)
+        self._warm_batch = FrontendConfig.max_batch
 
         if isinstance(source, (Index, ShardedIndex)):
             self._index = source
@@ -199,10 +230,21 @@ class SearchService:
             old.close()          # a ClusterSearcher owns a thread pool
         self.searcher = self._index.searcher(
             cache=self.superpost_cache, coalesce_gap=self.coalesce_gap)
+        if compiles():
+            _warm_kernels(self.searcher, self._warm_batch)
         # the snapshot this service serves until the next swap — result
         # caches key on it, leases pin it
         self._pin = self._reader_pin()
         self._lease_pins()
+
+    def warm(self, max_batch: int) -> None:
+        """Compile, on a backend that compiles, every kernel class that a
+        `search_batch` of up to `max_batch` queries can ask for, now and
+        whenever the searcher is reopened. A `Frontend` calls this with
+        its own `max_batch`."""
+        self._warm_batch = max_batch
+        if compiles():
+            _warm_kernels(self.searcher, max_batch)
 
     def _lease_pins(self) -> None:
         """Acquire leases on everything the new searcher pins, THEN

@@ -259,8 +259,8 @@ def test_intersect_batch_matches_single_and_oracle(Q, n_docs):
     rng = np.random.default_rng(Q * 7 + n_docs)
     batch = _random_ragged_batch(rng, Q, n_docs)
     bitmaps = postings_to_bitmap_batch(batch, n_docs)
-    out_p, cnt_p = intersect_batch(bitmaps, impl="pallas")
-    out_r, cnt_r = intersect_batch(bitmaps, impl="ref")
+    out_p, cnt_p = intersect_batch(bitmaps, impl="pallas", n_docs=n_docs)
+    out_r, cnt_r = intersect_batch(bitmaps, impl="ref", n_docs=n_docs)
     np.testing.assert_array_equal(np.asarray(out_p), np.asarray(out_r))
     np.testing.assert_array_equal(np.asarray(cnt_p), np.asarray(cnt_r))
     for q, posts in enumerate(batch):
@@ -279,7 +279,7 @@ def test_intersect_batch_property_ragged(seed):
     n_docs = int(rng.integers(32, 2000))
     batch = _random_ragged_batch(rng, int(rng.integers(1, 6)), n_docs)
     bitmaps = postings_to_bitmap_batch(batch, n_docs)
-    out, counts = intersect_batch(bitmaps, impl="pallas")
+    out, counts = intersect_batch(bitmaps, impl="pallas", n_docs=n_docs)
     out = np.asarray(out)
     for q, posts in enumerate(batch):
         oracle = intersect_sorted(posts)

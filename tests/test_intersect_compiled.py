@@ -1,8 +1,9 @@
 """The intersect kernels on the chip's compiler, and which mode runs them.
 
 Part one compiles all four entry points for a described TPU v5e at the
-widths a 1M-document deployment reaches (W = 31,250 words, Q = 64
-queries, L = S = 16, G = 16 shard units) — nothing runs, but Mosaic
+widths a 1M-document deployment reaches (W = 31,250 words, padded to
+its class of 32,768 by the batch ops, Q = 64 queries, L = S = 16, G = 16
+shard units) — nothing runs, but Mosaic
 refuses here whatever it would refuse on the chip, so these tests guard
 the compiled path on every CPU run. The topology is described inside a
 module fixture, never at import, and compiles run with the persistent
@@ -17,6 +18,7 @@ refs on the default backend — the Pallas interpreter here, Mosaic on a
 TPU — including a sweep of the cluster-fused kernel.
 """
 
+import functools
 import os
 
 import jax
@@ -30,10 +32,14 @@ from repro.kernels.intersect import (OP_AND, OP_ANDNOT, OP_OR,
                                      combine_cluster, combine_cluster_ref,
                                      intersect, intersect_batch,
                                      intersect_batch_ref, intersect_ref,
-                                     interpret_mode,
+                                     interpret_mode, launch_class,
                                      pack_cluster_programs, pack_programs,
-                                     pad_to_shape_class, postings_to_bitmap,
+                                     postings_to_bitmap,
                                      postings_to_bitmap_batch)
+from repro.kernels.intersect.kernel import (combine_batch_pallas,
+                                            combine_cluster_pallas,
+                                            intersect_batch_pallas)
+from repro.kernels.intersect.ops import pad_to_shape_class, shape_class
 
 W_1M = (1_000_000 + 31) // 32          # words of a 1M-document universe
 Q, L, S, G = 64, 16, 16, 16
@@ -66,15 +72,25 @@ def no_compile_cache():
 
 
 def _shapes(kernel):
+    """The op's entry point and operand shapes: the one-query op as given,
+    each batch op's kernel at the class its wrapper pads a 1M-document
+    unit's batch to (`launch_class`)."""
     u32, i32 = jnp.uint32, jnp.int32
+    c = launch_class(Q, L, W_1M, S, n_docs=1_000_000)
+    Qc, Lc, Wc, Sc = c["Qc"], c["Lc"], c["Wc"], c["Sc"]
     return {
         "intersect": (intersect, [((L, W_1M), u32)]),
-        "intersect_batch": (intersect_batch, [((Q, L, W_1M), u32)]),
-        "combine_batch": (combine_batch, [((Q, L, W_1M), u32),
-                                          ((Q, S, 3), i32)]),
-        "combine_cluster": (combine_cluster, [((G, Q, L, W_1M), u32),
-                                              ((G, Q, S, 3), i32)]),
+        "intersect_batch": (_compiled(intersect_batch_pallas),
+                            [((Qc, Lc, Wc), u32)]),
+        "combine_batch": (_compiled(combine_batch_pallas),
+                          [((Qc, Lc, Wc), u32), ((Qc, Sc, 3), i32)]),
+        "combine_cluster": (_compiled(combine_cluster_pallas),
+                            [((G, Qc, Lc, Wc), u32), ((G, Qc, Sc, 3), i32)]),
     }[kernel]
+
+
+def _compiled(entry):
+    return functools.partial(entry, interpret=False)
 
 
 @pytest.mark.parametrize("kernel", ["intersect", "intersect_batch",
@@ -82,7 +98,8 @@ def _shapes(kernel):
 def test_kernel_compiles_for_v5e(kernel, v5e_chip, no_compile_cache,
                                  monkeypatch):
     """Each public op, steered onto its TPU branch, lowers to a Mosaic
-    custom call and fits one v5e chip's HBM."""
+    custom call and fits one v5e chip's HBM, at the shape its wrapper
+    launches a 1M-document batch at."""
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     op, args = _shapes(kernel)
     specs = [jax.ShapeDtypeStruct(shape, dt, sharding=v5e_chip)
@@ -101,11 +118,13 @@ def test_oversized_program_batch_splits_for_smem(v5e_chip, no_compile_cache,
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     n_q, n_s = 8192, 32
     assert n_q * n_s * 3 > 2 * PROGRAM_WORDS
-    specs = [jax.ShapeDtypeStruct((n_q, 2, 128), jnp.uint32,
+    c = launch_class(n_q, 2, 128, n_s, n_docs=128 * 32)
+    specs = [jax.ShapeDtypeStruct((c["Qc"], c["Lc"], c["Wc"]), jnp.uint32,
                                   sharding=v5e_chip),
-             jax.ShapeDtypeStruct((n_q, n_s, 3), jnp.int32,
+             jax.ShapeDtypeStruct((c["Qc"], c["Sc"], 3), jnp.int32,
                                   sharding=v5e_chip)]
-    text = jax.jit(combine_batch).lower(*specs).compile().as_text()
+    text = jax.jit(_compiled(combine_batch_pallas)).lower(
+        *specs).compile().as_text()
     assert text.count("tpu_custom_call") >= 2
 
 
@@ -119,7 +138,7 @@ def test_program_batch_split_matches_ref(monkeypatch):
         [_random_postings(rng, n_l, n_docs) for _ in range(n_q)], n_docs)
     packed = pack_programs(_random_programs(rng, n_q, n_l), n_l)
     kernel.combine_batch_pallas.clear_cache()      # retrace with the patch
-    out_p, cnt_p = combine_batch(bm, packed)
+    out_p, cnt_p = combine_batch(bm, packed, n_docs=n_docs)
     kernel.combine_batch_pallas.clear_cache()      # and drop that trace
     out_r, cnt_r = combine_batch_ref(bm, packed)
     assert (np.asarray(out_p) == np.asarray(out_r)).all()
@@ -151,7 +170,8 @@ def test_unknown_backend_raises(backend, monkeypatch):
     with pytest.raises(UnsupportedBackendError, match=backend):
         intersect(bm)
     with pytest.raises(UnsupportedBackendError):
-        combine_batch(bm[None], pack_programs([[(OP_AND, 0, 1)]], 2))
+        combine_batch(bm[None], pack_programs([[(OP_AND, 0, 1)]], 2),
+                      n_docs=64 * 32)
     # the explicit oracle stays available on any backend
     out, _cnt = intersect(bm, impl="ref")
     assert (np.asarray(out) == 1).all()
@@ -193,7 +213,7 @@ def test_intersect_batch_vs_ref(Q, L, n_docs):
     rng = np.random.default_rng(Q * 7 + L)
     bm = postings_to_bitmap_batch(
         [_random_postings(rng, L, n_docs) for _ in range(Q)], n_docs)
-    out_c, cnt_c = intersect_batch(bm, impl="pallas")
+    out_c, cnt_c = intersect_batch(bm, impl="pallas", n_docs=n_docs)
     out_r, cnt_r = intersect_batch_ref(bm)
     assert (np.asarray(out_c) == np.asarray(out_r)).all()
     assert (np.asarray(cnt_c) == np.asarray(cnt_r)).all()
@@ -205,7 +225,8 @@ def test_combine_batch_vs_ref(Q, L, n_docs):
     bm = postings_to_bitmap_batch(
         [_random_postings(rng, L, n_docs) for _ in range(Q)], n_docs)
     packed = pack_programs(_random_programs(rng, Q, L), L)
-    out_c, cnt_c = combine_batch(bm, packed, impl="pallas")
+    out_c, cnt_c = combine_batch(bm, packed, impl="pallas",
+                                 n_docs=n_docs)
     out_r, cnt_r = combine_batch_ref(bm, packed)
     assert (np.asarray(out_c) == np.asarray(out_r)).all()
     assert (np.asarray(cnt_c) == np.asarray(cnt_r)).all()
@@ -219,7 +240,8 @@ def test_combine_cluster_vs_ref(G, Q, L, n_docs):
         for _ in range(G)])
     packed = pack_cluster_programs(
         [_random_programs(rng, Q, L) for _ in range(G)], L)
-    out_c, cnt_c = combine_cluster(bm, packed, impl="pallas")
+    out_c, cnt_c = combine_cluster(bm, packed, impl="pallas",
+                                   n_docs=n_docs)
     out_r, cnt_r = combine_cluster_ref(bm, packed)
     assert (np.asarray(out_c) == np.asarray(out_r)).all()
     assert (np.asarray(cnt_c) == np.asarray(cnt_r)).all()
@@ -235,7 +257,8 @@ def test_cluster_interpret_vs_ref(G, Q, L, n_docs):
         for _ in range(G)])
     packed = pack_cluster_programs(
         [_random_programs(rng, Q, L) for _ in range(G)], L)
-    out_p, cnt_p = combine_cluster(bm, packed, impl="pallas")
+    out_p, cnt_p = combine_cluster(bm, packed, impl="pallas",
+                                   n_docs=n_docs)
     out_r, cnt_r = combine_cluster_ref(bm, packed)
     assert out_p.shape == (G, Q, bm.shape[-1])
     assert cnt_p.shape == (G, Q)
@@ -255,10 +278,10 @@ def test_shape_class_padding_keeps_results(G, Q, L, n_docs):
         for _ in range(groups)])
     packed = pack_cluster_programs(
         [_random_programs(rng, Q, L) for _ in range(groups)], L)
-    x, p = pad_to_shape_class(bm, packed)
+    x, p = pad_to_shape_class(bm, packed, n_docs)
     *_, Qc, Lc, Wc = x.shape
-    assert (Qc, Lc, p.shape[-2]) == tuple(
-        1 << max(n - 1, 0).bit_length() for n in (Q, L, packed.shape[-2]))
+    assert (Qc, Lc, p.shape[-2]) == (
+        shape_class(Q), shape_class(L, 4), shape_class(packed.shape[-2], 4))
     assert Wc >= 1024 and Wc & (Wc - 1) == 0
     if G:
         got, cnt = combine_cluster_ref(x, p)
@@ -281,8 +304,8 @@ def test_cluster_counts_match_per_shard_popcounts():
         for _ in range(G)])
     progs = [_random_programs(rng, Q, L) for _ in range(G)]
     packed = pack_cluster_programs(progs, L)
-    _, cnt = combine_cluster(bm, packed, impl="pallas")
+    _, cnt = combine_cluster(bm, packed, impl="pallas", n_docs=n_docs)
     for g in range(G):
         _, cnt_g = combine_batch(bm[g], pack_programs(progs[g], L),
-                                 impl="pallas")
+                                 impl="pallas", n_docs=n_docs)
         assert (np.asarray(cnt[g]) == np.asarray(cnt_g)).all()

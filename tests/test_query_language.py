@@ -532,8 +532,10 @@ def test_combine_batch_matches_ref_and_sets(seed):
                for op, a, b in prog]
               for q, prog in enumerate(programs)]
     progs = pack_programs(padded, L_max)
-    out_p, cnt_p = combine_batch(bitmaps, progs, impl="pallas")
-    out_r, cnt_r = combine_batch(bitmaps, progs, impl="ref")
+    out_p, cnt_p = combine_batch(bitmaps, progs, impl="pallas",
+                                 n_docs=n_docs)
+    out_r, cnt_r = combine_batch(bitmaps, progs, impl="ref",
+                                 n_docs=n_docs)
     np.testing.assert_array_equal(np.asarray(out_p), np.asarray(out_r))
     np.testing.assert_array_equal(np.asarray(cnt_p), np.asarray(cnt_r))
     for q in range(Q):

@@ -179,8 +179,8 @@ def test_cluster_combine_reads_any_result_layout(monkeypatch, n_queries):
     from repro.kernels import intersect
     real = intersect.combine_cluster
 
-    def w_outermost(bitmaps, programs):
-        inter, counts = real(bitmaps, programs)
+    def w_outermost(bitmaps, programs, **kw):
+        inter, counts = real(bitmaps, programs, **kw)
         inter = np.asfortranarray(np.asarray(inter))
         assert not inter[0, 0].flags["C_CONTIGUOUS"]
         return inter, counts
@@ -199,13 +199,62 @@ def test_cluster_combine_reads_any_result_layout(monkeypatch, n_queries):
             words[-1].append({"a": (a, a + 1), "b": (b, b + 1)})
             expect[-1].append(np.intersect1d(a, b))
         commons.append(lambda w: False)
-    results, counts = combine_cluster_planned(plans, words, commons)
+    results, counts = combine_cluster_planned(plans, words, commons,
+                                              n_docs=150)
     for g in range(N_SHARDS):
         for j in range(n_queries):
             keys, lengths = results[g][j]
             np.testing.assert_array_equal(keys, expect[g][j])
             np.testing.assert_array_equal(lengths, expect[g][j] + 1)
             assert counts[g, j] == len(expect[g][j])
+
+
+def test_cluster_combine_counts_pairs_without_candidates():
+    """A (shard, query) pair whose words have no candidates counts 0,
+    wherever it lies on the grid: after a pair with candidates, before
+    one, and as a whole group's."""
+    rng = np.random.default_rng(5)
+    q = And((Term("a"), Term("b")))
+    empty = np.empty(0, np.uint64)
+    G, Q = 4, 3
+    plans, words, commons = [], [], []
+    for g in range(G):
+        plans.append([physical_plan(q, ())] * Q)
+        words.append([])
+        for j in range(Q):
+            if g == 3 or (g + j) % 2:
+                words[-1].append({"a": (empty, empty), "b": (empty, empty)})
+                continue
+            a = np.unique(rng.integers(0, 150, 60)).astype(np.uint64)
+            b = np.unique(rng.integers(0, 150, 60)).astype(np.uint64)
+            words[-1].append({"a": (a, a + 1), "b": (b, b + 1)})
+        commons.append(lambda w: False)
+    results, counts = combine_cluster_planned(plans, words, commons,
+                                              n_docs=150)
+    for g in range(G):
+        for j in range(Q):
+            keys, _lengths = results[g][j]
+            a, b = words[g][j]["a"][0], words[g][j]["b"][0]
+            np.testing.assert_array_equal(keys, np.intersect1d(a, b))
+            assert counts[g, j] == len(keys), (g, j)
+    assert counts[3].sum() == 0 and counts[:3].sum() > 0
+
+
+def test_fused_candidate_totals_equal_the_per_shard_legs(deployment):
+    """The gather's round-1 candidate totals, per query and per shard,
+    are those the per-shard legs count on the host; most (shard, query)
+    pairs of a 16-shard index hold no candidates."""
+    svc, texts = deployment["svc"], deployment["texts"]
+    fused = svc.search_batch(texts, top_k=100, impl="fused")
+    fused_shards = list(svc.searcher.last_scatter.shard_candidates)
+    legs = svc.search_batch(texts, top_k=100, impl="bitmap")
+    leg_shards = list(svc.searcher.last_scatter.shard_candidates)
+    assert [r.stats.n_candidates for r in fused] == \
+        [r.stats.n_candidates for r in legs]
+    assert fused_shards == leg_shards
+    assert sum(fused_shards) == sum(r.stats.n_candidates for r in fused)
+    assert 0 in fused_shards or any(
+        r.stats.n_candidates == 0 for r in fused)
 
 
 # ------------------------------------------------------------ completion

@@ -177,7 +177,13 @@ def test_served_batch_span_tree_and_identical_results(service):
     calls = [s for s in first if s.name == "combine.call"]
     assert {c.attrs["kernel"] for c in calls} == {"intersect_batch",
                                                   "combine_batch"}
-    assert all(set(c.attrs) == {"kernel", "Q", "L", "W"} for c in calls)
+    # the batch's own shape, and beside it the class it launched at
+    own = {"intersect_batch": {"Q", "L", "W"},
+           "combine_batch": {"Q", "L", "W", "S"}}
+    for c in calls:
+        shape = own[c.attrs["kernel"]]
+        assert set(c.attrs) == {"kernel"} | shape | {k + "c" for k in shape}
+        assert all(c.attrs[k + "c"] >= c.attrs[k] for k in shape)
     verify = [s for s in out if s.name == "searcher.verify"]
     assert all(0 <= v.attrs["accepted"] <= v.attrs["docs"] for v in verify)
     # no line holds the phrase "info block": its sample comes up short
