@@ -9,13 +9,13 @@ phrases) three ways:
 
   (a) `Frontend(SearchService(index))` — the default host path;
   (b) `SearchService.search_batch(..., impl="bitmap")` — the batched
-      engine on the `intersect_batch` / `combine_batch` kernels;
+      engine on the `combine_batch` kernel;
   (c) `cluster.searcher(fused=True).query_batch(...)` — the cluster-fused
       combine on the `combine_cluster` kernel.
 
 Every leg's document refs must equal `impl="sorted"` on the single index
-and a brute-force scan of the corpus. Before that, each of the four
-intersect kernels is compiled on the device (its text must hold a Mosaic
+and a brute-force scan of the corpus. Before that, each of the three
+intersect ops is compiled on the device (its text must hold a Mosaic
 `tpu_custom_call`) and checked bit-exact against its jnp reference.
 
 Usage, from the repository root on a machine with a TPU:
@@ -125,16 +125,12 @@ def _random_programs(rng, n: int, n_layers: int) -> np.ndarray:
 def _launch_specs(args) -> list[jax.ShapeDtypeStruct]:
     """The operand shapes a batch op launches its kernel at: its batch
     padded to the `launch_class` of a 1M-document unit."""
-    bm, *progs = args
+    bm, progs = args
     *lead, Q, L, W = bm.shape
-    c = ki.launch_class(Q, L, W, progs[0].shape[-2] if progs else None,
-                        n_docs=N_DOCS)
-    specs = [jax.ShapeDtypeStruct((*lead, c["Qc"], c["Lc"], c["Wc"]),
-                                  jnp.uint32)]
-    if progs:
-        specs.append(jax.ShapeDtypeStruct((*lead, c["Qc"], c["Sc"], 3),
-                                          jnp.int32))
-    return specs
+    c = ki.launch_class(Q, L, W, progs.shape[-2], n_docs=N_DOCS)
+    return [jax.ShapeDtypeStruct((*lead, c["Qc"], c["Lc"], c["Wc"]),
+                                 jnp.uint32),
+            jax.ShapeDtypeStruct((*lead, c["Qc"], c["Sc"], 3), jnp.int32)]
 
 
 def check_kernels(seed: int) -> None:
@@ -152,8 +148,6 @@ def check_kernels(seed: int) -> None:
     cl_progs = _random_programs(rng, G * 8, 4).reshape(G, 8, -1, 3)
     cases = {
         "intersect": (ki.intersect, None, (bm[0],)),
-        "intersect_batch": (ki.intersect_batch,
-                            kk.intersect_batch_pallas, (bm,)),
         "combine_batch": (ki.combine_batch, kk.combine_batch_pallas,
                           (bm, progs)),
         "combine_cluster": (ki.combine_cluster, kk.combine_cluster_pallas,

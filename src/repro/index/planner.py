@@ -36,9 +36,10 @@ match before verification can save it:
 The executor (searcher.py `execute_jobs`) is unchanged in shape: one
 shared superpost round, the candidate algebra in memory (NumPy set ops,
 or the batched Pallas `combine_batch` kernel under `impl="bitmap"`), one
-shared document round, per-node verification. Classic Term/And/Or trees
-and standalone Regex queries compile to exactly the jobs the pre-planner
-engine built — byte-identical requests, results, and stats.
+shared document round, per-node verification. Every query, a lone term
+or regex included, is one kind of job: a plan, its compiled program, and
+`matches` as its verifier. A Term/And/Or tree looks up `query_words` of
+the tree, in its order.
 """
 
 from __future__ import annotations
@@ -55,7 +56,7 @@ from ..core.hashing import word_fingerprint
 from ..core.sketch import intersect_sorted
 from ..data.tokenizer import parse_words
 from .query import (And, Not, Or, Phrase, Query, Regex, Term, normalize,
-                    query_words, regex_grams)
+                    regex_grams)
 
 
 class PureNegationError(ValueError):
@@ -196,39 +197,6 @@ def _bounded(q: Query) -> bool:
     raise TypeError(f"not a Query node: {type(q).__name__}")
 
 
-def _is_classic(q: Query) -> bool:
-    """Trees the pre-planner engine already executed: Term/And/Or only."""
-    if isinstance(q, Term):
-        return True
-    if isinstance(q, (And, Or)):
-        return all(_is_classic(s) for s in q.items)
-    return False
-
-
-def _classic_matches(q: Query, words: set[str]) -> bool:
-    if isinstance(q, Term):
-        return q.word in words
-    if isinstance(q, And):
-        return all(_classic_matches(s, words) for s in q.items)
-    assert isinstance(q, Or)
-    return any(_classic_matches(s, words) for s in q.items)
-
-
-def regex_prefilter(pattern: str, ngram: int,
-                    ) -> tuple[Query, "_re.Pattern[str]"]:
-    """Literal runs (≥ n chars) → AND of indexed n-grams (§IV-F)."""
-    from .builder import NGRAM_PREFIX
-    grams = regex_grams(pattern, ngram)
-    if not grams:
-        raise ValueError(
-            f"pattern {pattern!r} has no literal run of >= {ngram} "
-            "chars to prefilter on (a full corpus scan would be "
-            "required — rejected, like the paper's RegEx engines)")
-    q = And(tuple(Term(NGRAM_PREFIX + g) for g in grams)) \
-        if len(grams) > 1 else Term(NGRAM_PREFIX + grams[0])
-    return q, _compiled(pattern)
-
-
 # ------------------------------------------------------------ physical pass
 @dataclass
 class PhysicalPlan:
@@ -321,57 +289,40 @@ def physical_plan(tree: Query, units: tuple = ()) -> PhysicalPlan:
 # ----------------------------------------------------------- physical jobs
 @dataclass
 class Job:
-    """One query of a batch: lookup tree + round-2 acceptance filter.
+    """One query of a batch: its plan, and how its answer is cut and
+    fetched. Round 1 looks up the plan's words; round 2 accepts a
+    document when `accepts` holds for its lazy `DocContent`."""
 
-    Exactly one acceptance predicate is set. Classic tree queries filter
-    on the document's word set (computed once per unique document per
-    batch), classic regex jobs on the raw text, and planner-compiled
-    queries (`plan` set) on a lazy `DocContent` via per-node `matches`.
-    """
-
-    lookup_q: Query
-    accept_words: Callable[[set[str]], bool] | None = None
-    accept_text: Callable[[str], bool] | None = None
-    accept_doc: Callable[[DocContent], bool] | None = None
-    plan: PhysicalPlan | None = None
+    plan: PhysicalPlan
     top_k: int | None = None
     delta: float = 1e-6
     fetch_documents: bool = True
 
+    @property
+    def lookup_q(self) -> Query:
+        """The round-1 lookup tree: the AND of the plan's words."""
+        words = self.plan.lookup_words
+        return Term(words[0]) if len(words) == 1 else \
+            And(tuple(Term(w) for w in words))
 
-def _lookup_tree(words: list[str]) -> Query:
-    return Term(words[0]) if len(words) == 1 else \
-        And(tuple(Term(w) for w in words))
+    def accepts(self, content: DocContent) -> bool:
+        return matches(self.plan.tree, content)
 
 
 def make_job(q: Query, top_k: int | None = None,
              delta: float = 1e-6, fetch_documents: bool = True,
              units: tuple = ()) -> Job:
-    """Plan one query into a physical job.
-
-    Classic shapes (Term/And/Or trees; a standalone Regex) compile to
-    exactly the jobs the pre-planner engine built — same lookups in the
-    same order, same acceptance predicate — so existing workloads stay
-    byte-identical. Everything else goes through the physical planner.
-    """
-    if isinstance(q, Regex):
-        _check_regex_units(q, units)
-        lookup_q, compiled = regex_prefilter(q.pattern, q.ngram)
-        return Job(lookup_q=lookup_q,
-                   accept_text=lambda t, c=compiled: bool(c.search(t)),
-                   top_k=top_k, delta=delta,
-                   fetch_documents=fetch_documents)
+    """Plan one query into a physical job. A standalone regex with no
+    literal run to prefilter on is refused with a `ValueError` of its own
+    (§IV-F), before the planner's `PureNegationError`."""
+    if isinstance(q, Regex) and not regex_grams(q.pattern, q.ngram):
+        raise ValueError(
+            f"pattern {q.pattern!r} has no literal run of >= {q.ngram} "
+            "chars to prefilter on (a full corpus scan would be "
+            "required — rejected, like the paper's RegEx engines)")
     tree = normalize(q)
     _check_regex_units(tree, units)
-    if _is_classic(tree):
-        return Job(lookup_q=tree,
-                   accept_words=lambda ws, q=tree: _classic_matches(q, ws),
-                   top_k=top_k, delta=delta,
-                   fetch_documents=fetch_documents)
-    plan = physical_plan(tree, units)
-    return Job(lookup_q=_lookup_tree(plan.lookup_words),
-               accept_doc=lambda c, q=tree: matches(q, c),
-               plan=plan, top_k=top_k, delta=delta,
+    return Job(plan=physical_plan(tree, units), top_k=top_k, delta=delta,
                fetch_documents=fetch_documents)
 
 
@@ -400,6 +351,7 @@ def _compile_steps(plan: PhysicalPlan,
     Leaves are (keys, lengths) candidate arrays; steps are
     (op, ref_a, ref_b) over slots — leaves first, then one slot per
     step, exactly the layout `kernels.intersect.combine_batch` expects.
+    A program of no steps is its one leaf.
     """
     from .builder import NGRAM_PREFIX
     leaves: list[tuple[np.ndarray, np.ndarray]] = []
@@ -466,11 +418,7 @@ def _compile_steps(plan: PhysicalPlan,
         kind, i = ref
         return i if kind == "l" else L + i
 
-    resolved = [(op, slot(a), slot(b)) for op, a, b in steps]
-    if root[0] == "l" and not resolved:
-        # single-leaf plan: one identity step keeps the program non-empty
-        resolved = [(OP_AND, slot(root), slot(root))]
-    return leaves, resolved
+    return leaves, [(op, slot(a), slot(b)) for op, a, b in steps]
 
 
 def _eval_steps(leaves: list[tuple[np.ndarray, np.ndarray]],
@@ -552,21 +500,74 @@ def _launch_operands(rows: list[list[np.ndarray]],
     of each job's leaf positions, zero-padded to the batch's L (at least
     one) layers — a job with none reads its zero layer 0 as the empty
     set — and (n, S, 3) programs, their step slots moved up past each
-    job's added layers. A job with no layers keeps its program as it
-    is: its slots name layer 0, not steps."""
+    job's added layers."""
     from ..kernels.intersect import pack_programs, postings_to_bitmap_batch
 
-    bitmaps = postings_to_bitmap_batch(rows, n_bits, fill=0)
+    bitmaps = postings_to_bitmap_batch(rows, n_bits)
     if not bitmaps.shape[1]:
         bitmaps = np.zeros((len(rows), 1, bitmaps.shape[2]), np.uint32)
     L = bitmaps.shape[1]
     padded = []
     for posts, steps in zip(rows, programs):
         n = len(posts)
-        shift = L - n if n else 0
-        padded.append([(op, a + shift if a >= n else a,
-                        b + shift if b >= n else b) for op, a, b in steps])
+        padded.append([(op, a + L - n if a >= n else a,
+                        b + L - n if b >= n else b) for op, a, b in steps])
     return bitmaps, pack_programs(padded, L)
+
+
+Program = tuple[list[tuple[np.ndarray, np.ndarray]],
+                list[tuple[int, int, int]]]       # (leaves, steps)
+_EMPTY = (np.empty(0, np.uint64), np.empty(0, np.uint64))
+
+
+def _launch_programs(programs: list[Program], *, n_docs: int,
+                     groups: int = 0,
+                     ) -> tuple[list[tuple[np.ndarray, np.ndarray]],
+                                np.ndarray]:
+    """Run compiled programs as ONE kernel launch: build each program's
+    universe and operands, call `combine_batch` — or, with `groups` > 0,
+    `combine_cluster` over the programs taken as (groups, Q) shard-major
+    — and unpack each result row into its universe's keys and lengths.
+    A program whose leaves hold no keys still takes its slot: with no
+    layers and no steps it reads its zero layer 0, the empty set. Returns
+    the candidate pairs in the programs' order and the kernel's counts.
+    `n_docs` fixes the launch's width class."""
+    from ..kernels import intersect as kernels
+
+    kernel = "combine_cluster" if groups else "combine_batch"
+    with spans.span("combine.build") as s:
+        universes: list[Universe | None] = []
+        rows: list[list[np.ndarray]] = []
+        for leaves, _steps in programs:
+            uni, posts = _universe(leaves)
+            universes.append(uni)
+            rows.append(posts)
+        n_bits = max((len(u[0]) for u in universes if u is not None),
+                     default=1)
+        bitmaps, progs = _launch_operands(
+            rows, [steps if uni is not None else []
+                   for (_l, steps), uni in zip(programs, universes)], n_bits)
+        Q, L, W = bitmaps.shape
+        S = progs.shape[1]
+        shape = dict(kernel=kernel)
+        if groups:
+            Q //= groups
+            bitmaps = bitmaps.reshape(groups, Q, L, W)
+            progs = progs.reshape(groups, Q, S, 3)
+            shape["G"] = groups
+        # the work the batch asks for, and the class it launches at
+        shape.update(Q=Q, L=L, W=W, S=S)
+        shape.update(kernels.launch_class(Q, L, W, S, n_docs=n_docs))
+        s.set(**shape)
+    with spans.span("combine.call") as s:
+        s.set(**shape)
+        inter, counts = getattr(kernels, kernel)(bitmaps, progs,
+                                                 n_docs=n_docs)
+    with spans.span("combine.unpack") as s:
+        s.set(**shape)
+        out = [_EMPTY if uni is None else _selected(uni, row)
+               for uni, row in zip(universes, inter.reshape(-1, W))]
+    return out, counts
 
 
 def combine_planned(plans: list[PhysicalPlan],
@@ -577,53 +578,33 @@ def combine_planned(plans: list[PhysicalPlan],
                     ) -> list[tuple[np.ndarray, np.ndarray]]:
     """Evaluate several planned queries' candidate algebra for one unit.
 
-    `impl="sorted"` runs NumPy set ops per query; `impl="bitmap"` maps
-    each query's leaf postings into a dense per-query universe and
-    evaluates every compiled program in ONE batched Pallas
-    `combine_batch` call (AND/OR/ANDNOT fused per document tile), whose
-    width class `n_docs`, the unit's document count, fixes.
+    Each plan compiles to a program. A program of no steps is its one
+    leaf, and one whose leaves hold no keys is empty: neither is
+    combined. The rest run as NumPy set ops under `impl="sorted"`, and
+    under `impl="bitmap"` in ONE batched Pallas `combine_batch` launch
+    (AND/OR/ANDNOT fused per document tile), whose width class `n_docs`,
+    the unit's document count, fixes.
     """
-    if impl != "bitmap":
-        return [_eval_steps(*_compile_steps(p, pw, is_common))
+    out: list[tuple[np.ndarray, np.ndarray]] = []
+    launch: list[int] = []
+    programs = [_compile_steps(p, pw, is_common)
                 for p, pw in zip(plans, per_words)]
-
-    from ..kernels.intersect import combine_batch, launch_class
-
-    with spans.span("combine.build") as s:
-        compiled = [_compile_steps(p, pw, is_common)
-                    for p, pw in zip(plans, per_words)]
-        universes: list[Universe | None] = []
-        rows: list[list[np.ndarray]] = []
-        programs: list[list[tuple[int, int, int]]] = []
-        out: list[tuple[np.ndarray, np.ndarray] | None] = [None] * len(plans)
-        for j, (leaves, steps) in enumerate(compiled):
-            uni, posts = _universe(leaves)
-            universes.append(uni)
-            if uni is None:
-                out[j] = (np.empty(0, np.uint64), np.empty(0, np.uint64))
-                continue
-            rows.append(posts)
-            programs.append(steps)
-        if rows:
-            n_bits = max(len(u[0]) for u in universes if u is not None)
-            bitmaps, progs = _launch_operands(rows, programs, n_bits)
-            shape = dict(kernel="combine_batch",
-                         **dict(zip("QLW", bitmaps.shape)), S=progs.shape[1])
-            shape.update(launch_class(*bitmaps.shape, progs.shape[1],
-                                      n_docs=n_docs))
-            s.set(**shape)
-    if not rows:
-        return out  # type: ignore[return-value]
-
-    with spans.span("combine.call") as s:
-        s.set(**shape)
-        inter, _counts = combine_batch(bitmaps, progs, n_docs=n_docs)
-    with spans.span("combine.unpack"):
-        rows_of = iter(inter)
-        for j, uni in enumerate(universes):
-            if uni is not None:
-                out[j] = _selected(uni, next(rows_of))
-    return out  # type: ignore[return-value]
+    for j, (leaves, steps) in enumerate(programs):
+        if not steps:
+            out.append(leaves[0])
+        elif not any(len(k) for k, _l in leaves):
+            out.append(_EMPTY)
+        elif impl != "bitmap":
+            out.append(_eval_steps(leaves, steps))
+        else:
+            out.append(_EMPTY)
+            launch.append(j)
+    if launch:
+        results, _counts = _launch_programs([programs[j] for j in launch],
+                                            n_docs=n_docs)
+        for j, res in zip(launch, results):
+            out[j] = res
+    return out
 
 
 def combine_cluster_planned(plans_by_group: list[list[PhysicalPlan]],
@@ -637,64 +618,27 @@ def combine_cluster_planned(plans_by_group: list[list[PhysicalPlan]],
     Pallas call (`kernels.intersect.combine_cluster`).
 
     Group g is one shard unit: `plans_by_group[g][q]`,
-    `per_words_by_group[g][q]`, and `is_common_by_group[g]` follow
-    `combine_planned`'s bitmap path per group, but instead of one
-    `combine_batch` launch per unit the whole cluster's programs run on
-    a single (shard, query, tile) grid, whose width class `n_docs`, the
-    largest unit's document count, fixes. Returns `(results, counts)`:
+    `per_words_by_group[g][q]`, and `is_common_by_group[g]` compile as
+    `combine_planned`'s do, but every (shard, query) pair, one of a
+    single leaf or of no keys included, takes its slot on a single
+    (shard, query, tile) grid, whose width class `n_docs`, the largest
+    unit's document count, fixes. Returns `(results, counts)`:
     `results[g][q]` is the sorted `(keys, lengths)` candidate pair and
     `counts` a (G, Q) int64 array of per-(group, query) candidate
     totals — exactly the round-1 statistics `shard_quotas` consumes.
     """
-    from ..kernels.intersect import combine_cluster, launch_class
-
     G = len(plans_by_group)
     Q = len(plans_by_group[0]) if G else 0
     if not G or not Q:
         return [[] for _ in range(G)], np.zeros((G, Q), dtype=np.int64)
-    with spans.span("combine.build") as s:
-        # (shard, query) pairs flattened shard-major, as the kernel's
-        # query axis takes them
-        compiled = [_compile_steps(plans_by_group[g][q],
-                                   per_words_by_group[g][q],
-                                   is_common_by_group[g])
-                    for g in range(G) for q in range(Q)]
-        universes: list[Universe | None] = []
-        rows: list[list[np.ndarray]] = []
-        programs: list[list[tuple[int, int, int]]] = []
-        for leaves, steps in compiled:
-            uni, posts = _universe(leaves)
-            universes.append(uni)
-            rows.append(posts)
-            # an empty universe still needs a program on the grid:
-            # AND(0, 0) of its zero layer 0 is the empty set
-            programs.append(steps if uni is not None else [(OP_AND, 0, 0)])
-        n_bits = max((len(u[0]) for u in universes if u is not None),
-                     default=1)
-        bitmaps, progs = _launch_operands(rows, programs, n_bits)
-        bitmaps = bitmaps.reshape(G, Q, *bitmaps.shape[1:])
-        progs = progs.reshape(G, Q, *progs.shape[1:])
-        # the work the batch asks for, and the class it launches at
-        shape = dict(kernel="combine_cluster", G=G, Q=Q,
-                     L=bitmaps.shape[2], W=bitmaps.shape[3],
-                     S=progs.shape[2])
-        shape.update(launch_class(Q, shape["L"], shape["W"], shape["S"],
-                                  n_docs=n_docs))
-        s.set(**shape)
-    with spans.span("combine.call") as s:
-        s.set(**shape)
-        inter, counts = combine_cluster(bitmaps, progs, n_docs=n_docs)
-        counts = counts.astype(np.int64)
-    with spans.span("combine.unpack") as s:
-        s.set(**shape)
-        results: list[list[tuple[np.ndarray, np.ndarray]]] = \
-            [[(np.empty(0, np.uint64), np.empty(0, np.uint64))] * Q
-             for _ in range(G)]
-        for i, uni in enumerate(universes):
-            if uni is not None:
-                g, q = divmod(i, Q)
-                results[g][q] = _selected(uni, inter[g, q])
-    return results, counts
+    # (shard, query) pairs flattened shard-major, as the kernel's query
+    # axis takes them
+    flat, counts = _launch_programs(
+        [_compile_steps(plans_by_group[g][q], per_words_by_group[g][q],
+                        is_common_by_group[g])
+         for g in range(G) for q in range(Q)], n_docs=n_docs, groups=G)
+    return [flat[g * Q:(g + 1) * Q] for g in range(G)], \
+        counts.astype(np.int64)
 
 
 # ----------------------------------------------------- global top-K budget
@@ -743,5 +687,5 @@ def shard_quotas(counts: list[int], k: int, F0s: list[float],
 
 __all__ = ["PureNegationError", "GramlessIndexError", "PhysicalPlan",
            "Job", "DocContent", "make_job", "plan_batch", "physical_plan",
-           "matches", "regex_prefilter", "combine_planned",
+           "matches", "combine_planned",
            "combine_cluster_planned", "shard_quotas"]

@@ -31,9 +31,7 @@ serial and batched execution are result-identical by construction.
 Queries arrive as trees of the composable query language (Term/And/Or/
 Not/Phrase/Regex — docs/query_language.md); the logical→physical planner
 (`index/planner.py`) lowers each tree to a lookup word set, a candidate
-algebra, and a content verifier before the phases run. Classic
-Term/And/Or and standalone-Regex shapes compile to the pre-planner jobs
-bit-for-bit.
+algebra, and a content verifier before the phases run.
 
 Since the lifecycle redesign (docs/index_lifecycle.md) the executor is
 **multi-unit**: the same plan/fetch/decode pipeline fans one query batch
@@ -66,11 +64,9 @@ from ..storage.transport import (SimCloudTransport, StorageTransport,
                                  as_transport)
 from . import codec
 from .fetch_plan import coalesce_requests, slice_payloads
-from .planner import (DocContent, Job as _Job,
-                      _classic_matches as _matches, _selected,
-                      _universe, combine_planned,
-                      make_job, plan_batch, regex_prefilter)
-from .query import And, Or, Query, Regex, Term, query_words
+from .planner import (DocContent, Job as _Job, combine_planned, make_job,
+                      plan_batch)
+from .query import Query, Regex, Term, query_words
 
 
 @dataclass
@@ -408,12 +404,10 @@ class Searcher:
         Accepts any query-language tree (Term/And/Or/Not/Phrase/Regex,
         composed freely — see docs/query_language.md) plus raw strings
         (single terms). Every query goes through the logical→physical
-        planner (`index/planner.py`); classic Term/And/Or and standalone
-        Regex shapes compile to exactly the pre-planner jobs, so their
-        requests and results stay byte-identical. Results equal per-query
-        `query`; only the (simulated) latency and request count differ.
-        With `impl="bitmap"`, candidate combines run through the batched
-        Pallas kernels (`kernels/intersect`).
+        planner (`index/planner.py`). Results equal per-query `query`;
+        only the (simulated) latency and request count differ. With
+        `impl="bitmap"`, candidate combines run through the batched
+        Pallas kernel (`kernels/intersect`).
         """
         with spans.span("searcher.plan") as s:
             jobs = plan_batch(queries, units=(self,), top_k=top_k)
@@ -888,9 +882,8 @@ def _fetch_and_filter_units(units: list[Searcher], jobs: list[_Job],
 
     with spans.span("searcher.verify") as s:
         # decode-once: a document wanted by several queries is utf-8
-        # decoded (and tokenized, for word/content filters) a single time —
-        # one DocContent serves classic word filters and planner verifiers
-        texts_u: list[str | None] = [None] * len(requests)
+        # decoded (and tokenized, for a verifier that reads words) a
+        # single time — one lazy DocContent serves every job's verifier
         content_u: list[DocContent | None] = [None] * len(requests)
         # a doc indexed by several units is ONE false positive for a job, as
         # it would be in a monolithic rebuild — dedupe rejections by identity
@@ -899,25 +892,14 @@ def _fetch_and_filter_units(units: list[Searcher], jobs: list[_Job],
             for j, refs in refs_by_job.items():
                 if not refs:     # done after round 1 — no doc round for it
                     continue
-                job = jobs[j]
                 for ref in refs:
                     i = uniq[(ref.blob, ref.offset, ref.length)]
-                    if texts_u[i] is None:
+                    if content_u[i] is None:
                         payload = payloads[i]
                         assert payload is not None
-                        texts_u[i] = payload.decode("utf-8")
-                    text = texts_u[i]
-                    if job.accept_text is not None:
-                        ok = job.accept_text(text)
-                    else:
-                        if content_u[i] is None:
-                            content_u[i] = DocContent(text)
-                        if job.accept_doc is not None:
-                            ok = job.accept_doc(content_u[i])
-                        else:
-                            ok = job.accept_words(content_u[i].words)
-                    if ok:
-                        texts_of[u][j].append(text)
+                        content_u[i] = DocContent(payload.decode("utf-8"))
+                    if jobs[j].accepts(content_u[i]):
+                        texts_of[u][j].append(content_u[i].text)
                         refs_of[u][j].append(ref)
                     elif i not in rejected.setdefault(j, set()):
                         rejected[j].add(i)
@@ -935,108 +917,9 @@ def _combine_jobs(jobs: list[_Job],
                   impl: str,
                   unit: "Searcher",
                   ) -> list[tuple[np.ndarray, np.ndarray]]:
-    """Per-job candidate combine for one unit.
-
-    Classic jobs run the ∪/∩ distribution (`impl="bitmap"` batches every
-    multi-term AND through one `intersect_batch` Pallas call, exactly as
-    before the planner); planner-compiled jobs evaluate their candidate
-    algebra — AND/OR plus exact-common-word ANDNOT — via
-    `planner.combine_planned` (one fused `combine_batch` Pallas call for
-    the whole planned set under `impl="bitmap"`).
-    """
-    out: list[tuple[np.ndarray, np.ndarray] | None] = [None] * len(jobs)
-    bitmap_jobs: list[int] = []
-    planned_jobs: list[int] = []
-    for j, (job, per_word) in enumerate(zip(jobs, per_word_list)):
-        q = job.lookup_q
-        if job.plan is not None:
-            planned_jobs.append(j)
-        elif impl == "bitmap" and isinstance(q, And) \
-                and all(isinstance(s, Term) for s in q.items) \
-                and len(per_word) >= 2:
-            bitmap_jobs.append(j)
-        else:
-            out[j] = _combine(q, per_word)
-    if bitmap_jobs:
-        parts_list = [[per_word_list[j][w]
-                       for w in query_words(jobs[j].lookup_q)]
-                      for j in bitmap_jobs]
-        for j, res in zip(bitmap_jobs,
-                          _bitmap_and_batch(parts_list, unit.n_docs)):
-            out[j] = res
-    if planned_jobs:
-        is_common = lambda w: word_fingerprint(w) in unit.common  # noqa: E731
-        results = combine_planned(
-            [jobs[j].plan for j in planned_jobs],
-            [per_word_list[j] for j in planned_jobs],
-            is_common, impl=impl, n_docs=unit.n_docs)
-        for j, res in zip(planned_jobs, results):
-            out[j] = res
-    return out  # type: ignore[return-value]
-
-
-def _combine(q: Query, per_word: dict[str, tuple[np.ndarray, np.ndarray]],
-             ) -> tuple[np.ndarray, np.ndarray]:
-    """Distribute ∪/∩ over per-word candidates (paper §IV-F)."""
-    if isinstance(q, Term):
-        return per_word[q.word]
-    parts = [_combine(sub, per_word) for sub in q.items]
-    keys_list = [k for k, _l in parts]
-    if isinstance(q, And):
-        keys = intersect_sorted(keys_list)
-    else:
-        assert isinstance(q, Or)
-        keys = np.unique(np.concatenate(keys_list)) if keys_list else \
-            np.empty(0, np.uint64)
-    # recover lengths from any part containing each key
-    lengths = np.zeros(len(keys), dtype=np.uint64)
-    for k, l in parts:
-        idx = np.searchsorted(k, keys)
-        idx = np.clip(idx, 0, max(len(k) - 1, 0))
-        if len(k):
-            hit = k[idx] == keys
-            lengths[hit] = l[idx[hit]]
-    return keys, lengths
-
-
-def _bitmap_and_batch(parts_list: list[list[tuple[np.ndarray, np.ndarray]]],
-                      n_docs: int,
-                      ) -> list[tuple[np.ndarray, np.ndarray]]:
-    """Batched multi-way AND via the Pallas bitmap kernel.
-
-    Each job's posting keys are mapped into a dense per-job universe
-    (the union of its words' candidate keys); all jobs' bitsets are then
-    intersected in ONE `intersect_batch` call, ragged L padded with
-    all-ones layers (the AND identity) and the launch's width class
-    fixed by `n_docs`, the unit's document count.
-    """
-    from ..kernels.intersect import (intersect_batch, launch_class,
-                                     postings_to_bitmap_batch)
-
-    with spans.span("combine.build") as s:
-        universes = []
-        rows: list[list[np.ndarray]] = []
-        for parts in parts_list:
-            if any(len(k) == 0 for k, _l in parts):
-                universes.append(None)      # empty AND — no kernel work
-                continue
-            uni, posts = _universe(parts)
-            universes.append(uni)
-            rows.append(posts)
-        if rows:
-            n_bits = max(len(u[0]) for u in universes if u is not None)
-            bitmaps = postings_to_bitmap_batch(rows, n_bits)
-            shape = dict(kernel="intersect_batch",
-                         **dict(zip("QLW", bitmaps.shape)))
-            shape.update(launch_class(*bitmaps.shape, n_docs=n_docs))
-            s.set(**shape)
-
-    if rows:
-        with spans.span("combine.call") as s:
-            s.set(**shape)
-            inter, _counts = intersect_batch(bitmaps, n_docs=n_docs)
-    with spans.span("combine.unpack"):
-        empty = (np.empty(0, dtype=np.uint64), np.empty(0, dtype=np.uint64))
-        rows_of = iter(inter) if rows else iter(())
-        return [empty if uni is None else _selected(uni, next(rows_of))
-                for uni in universes]
+    """Per-job candidate combine for one unit: every job's plan through
+    `planner.combine_planned` (one `combine_batch` launch for the batch
+    under `impl="bitmap"`)."""
+    return combine_planned([job.plan for job in jobs], per_word_list,
+                           lambda w: word_fingerprint(w) in unit.common,
+                           impl=impl, n_docs=unit.n_docs)

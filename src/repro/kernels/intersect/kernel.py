@@ -1,25 +1,23 @@
-"""Pallas TPU kernels: L-way bitmap intersection + popcount.
+"""Pallas TPU kernel: the candidate combine over bitmaps + popcount.
 
 The IoU Sketch query combine (paper §II-C): L superposts arrive as
-document-space bitsets; the final postings list is their intersection.
-On TPU we tile the document axis through VMEM in (8, 128)-aligned blocks
-and fuse AND-reduce with population count in one pass, so candidate
+document-space bitsets; the final postings list is their intersection,
+and a query tree's candidates are AND / OR / ANDNOT of its words'. On
+TPU we tile the document axis through VMEM in (8, 128)-aligned blocks
+and fuse the combine with population count in one pass, so candidate
 counting (needed by top-K sampling, Eq. 6) costs no extra HBM traffic.
 
-Two Mosaic kernels serve four entry points:
+One Mosaic kernel body, `_combine_kernel`, serves two entry points:
 
-  * `intersect_batch_pallas` — a whole query batch: bitmaps (Q, L, W),
-    2-D grid over (query, tile) so every query's AND tree runs in ONE
-    `pallas_call` — the kernel-side half of the batched query engine
-    (ragged batches are padded with all-ones layers, the AND identity);
-  * `intersect_pallas` — one query, (L, W): a batch of one;
-  * `combine_batch_pallas` — the query-planner generalization: each
-    query carries a tiny compiled program of AND / OR / ANDNOT steps
-    over its layers (the candidate-set algebra of an arbitrary boolean
-    tree), evaluated slot-machine style per document tile. Programs are
-    padded to one static step count; padding steps re-AND the running
-    result with itself (the identity), so raggedness costs a few no-op
-    vector ops, never a second `pallas_call`;
+  * `combine_batch_pallas` — a whole query batch: bitmaps (Q, L, W) and
+    per query a tiny compiled program of AND / OR / ANDNOT steps over
+    its layers (the candidate-set algebra of an arbitrary boolean tree),
+    evaluated slot-machine style per document tile on a 2-D (query,
+    tile) grid, so every query's tree runs in ONE `pallas_call`.
+    Programs are padded to one static step count; padding steps re-AND
+    the running result with itself (the identity), so raggedness costs
+    a few no-op vector ops, never a second `pallas_call`. A plain L-way
+    intersection is the AND chain over the layers;
   * `combine_cluster_pallas` — the serving-tier generalization: a whole
     CLUSTER's combine work — every (shard, query) pair carries its own
     program over its own layers — runs as ONE `pallas_call`, the
@@ -108,14 +106,6 @@ def _tile_counts(acc: jnp.ndarray) -> jnp.ndarray:
     return out
 
 
-def _and_kernel(bm_ref, out_ref, cnt_ref):
-    acc = bm_ref[0, 0]                      # (rows, 128) uint32
-    for l in range(1, bm_ref.shape[1]):     # L is static — unrolled AND tree
-        acc = jnp.bitwise_and(acc, bm_ref[0, l])
-    out_ref[0] = acc
-    cnt_ref[0] = _tile_counts(acc)
-
-
 def _combine_kernel(prog_ref, bm_ref, out_ref, cnt_ref, slots_ref):
     """Evaluate one query's combine program on one document tile.
 
@@ -146,45 +136,6 @@ def _combine_kernel(prog_ref, bm_ref, out_ref, cnt_ref, slots_ref):
         slots_ref[n_layers + s] = acc
     out_ref[0] = acc
     cnt_ref[0] = _tile_counts(acc)
-
-
-@functools.partial(jax.jit, static_argnames=("interpret",))
-def intersect_batch_pallas(bitmaps: jnp.ndarray, *, interpret: bool,
-                           ) -> tuple[jnp.ndarray, jnp.ndarray]:
-    """bitmaps: (Q, L, W) uint32 → (intersections (Q, W), counts (Q,)).
-
-    Grid is (query, tile): program (q, i) ANDs the i-th document tile of
-    query q's L layers and emits its partial popcounts — a whole batch of
-    multi-term queries combines in one fused pass.
-    """
-    Q, L, W = bitmaps.shape
-    rows = _tile_rows(W, 2 * L + 2)
-    x = _lane_dense(bitmaps, rows)
-    R = x.shape[2]
-    n_tiles = R // rows
-    out, counts = pl.pallas_call(
-        _and_kernel,
-        grid=(Q, n_tiles),
-        in_specs=[pl.BlockSpec((1, L, rows, LANES),
-                               lambda q, i: (q, 0, i, 0))],
-        out_specs=[pl.BlockSpec((1, rows, LANES), lambda q, i: (q, i, 0)),
-                   pl.BlockSpec((1, SUBLANES, LANES),
-                                lambda q, i: (q, i, 0))],
-        out_shape=[jax.ShapeDtypeStruct((Q, R, LANES), jnp.uint32),
-                   jax.ShapeDtypeStruct((Q, n_tiles * SUBLANES, LANES),
-                                        jnp.uint32)],
-        interpret=interpret,
-    )(x)
-    return (out.reshape(Q, R * LANES)[:, :W],
-            jnp.sum(counts, axis=(1, 2), dtype=jnp.uint32))
-
-
-@functools.partial(jax.jit, static_argnames=("interpret",))
-def intersect_pallas(bitmaps: jnp.ndarray, *, interpret: bool,
-                     ) -> tuple[jnp.ndarray, jnp.ndarray]:
-    """bitmaps: (L, W) uint32 → (intersection (W,), total count ())."""
-    out, counts = intersect_batch_pallas(bitmaps[None], interpret=interpret)
-    return out[0], counts[0]
 
 
 def _combine_call(programs: jnp.ndarray, x: jnp.ndarray, rows: int,

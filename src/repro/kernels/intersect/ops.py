@@ -6,13 +6,15 @@ decided here and nowhere else, from the default backend: compiled
 `UnsupportedBackendError` anywhere else. `impl="ref"` selects the
 pure-jnp oracle explicitly; no backend falls back to it.
 
-The shape a kernel compiles at is decided here too, and nowhere else:
-`intersect_batch`, `combine_batch` and `combine_cluster` pad every batch
-to its `launch_class` — Q a power of two, L and S powers of two of at
-least 4, W the class of the index unit's document count — and cut the
-result back to the batch's own rows and words. A stream of batches then
-runs a few compiled programs, which `warm_shape_classes` can compile
-ahead of the first batch.
+Two batch entry points, `combine_batch` (one index unit) and
+`combine_cluster` (every shard unit of a cluster), run the one kernel
+body of `kernel.py`; `intersect`, one query's L-way AND, is its AND
+chain. The shape a batch compiles at is decided here too, and nowhere
+else: both batch ops pad every batch to its `launch_class` — Q a power
+of two, L and S powers of two of at least 4, W the class of the index
+unit's document count — and cut the result back to the batch's own rows
+and words. A stream of batches then runs a few compiled programs, which
+`warm_shape_classes` can compile ahead of the first batch.
 """
 
 from __future__ import annotations
@@ -22,10 +24,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from .kernel import (OP_AND, OP_ANDNOT, OP_OR, combine_batch_pallas,
-                     combine_cluster_pallas, intersect_batch_pallas,
-                     intersect_pallas)
-from .ref import (combine_batch_ref, combine_cluster_ref,
-                  intersect_batch_ref, intersect_ref)
+                     combine_cluster_pallas)
+from .ref import combine_batch_ref, combine_cluster_ref, intersect_ref
 
 
 class UnsupportedBackendError(RuntimeError):
@@ -63,23 +63,20 @@ def postings_to_bitmap(postings: list[np.ndarray], n_docs: int) -> np.ndarray:
 
 
 def postings_to_bitmap_batch(postings_batch: list[list[np.ndarray]],
-                             n_docs: int, fill: int = 0xFFFFFFFF,
-                             ) -> np.ndarray:
+                             n_docs: int) -> np.ndarray:
     """Ragged batch of doc-id lists → (Q, L_max, W) uint32 bitsets.
 
-    Queries with fewer than L_max postings lists are padded with `fill`
-    layers: all-ones by default — the AND identity — so one fused kernel
-    call handles a batch of queries with different term counts. The
-    combine kernels' programs never read a query's added layers, and
-    pass `fill=0`, so that a query with no layers reads as empty. Each
-    layer is packed from a flag per document up to its query's largest
-    id, so a query of a few ids costs a few words whatever W is.
+    Queries with fewer than L_max postings lists are padded with zero
+    layers, which a combine program never reads, so one fused kernel
+    call handles a batch of queries with different term counts; a query
+    with no layers reads as empty. Each layer is packed from a flag per
+    document up to its query's largest id, so a query of a few ids costs
+    a few words whatever W is.
     """
     L_max = max((len(p) for p in postings_batch), default=0)
     W = (n_docs + 31) // 32
     out = np.zeros((len(postings_batch), L_max, W), dtype=np.uint32)
     for q, posts in enumerate(postings_batch):
-        out[q, len(posts):] = fill
         top = max((int(np.max(d)) + 1 for d in posts if len(d)), default=0)
         flags = np.zeros(-(-top // 32) * 32, np.bool_)
         for l, docs in enumerate(posts):
@@ -106,11 +103,19 @@ def bitmap_to_docs(bitmap: np.ndarray, n_bits: int | None = None,
 
 
 def intersect(bitmaps, impl: str = "pallas"):
-    """(L, W) uint32 → (bitmap (W,), count ()). impl: pallas | ref."""
+    """(L, W) uint32 → (bitmap (W,), count ()). impl: pallas | ref.
+
+    One query's L-way AND: the AND chain over its layers, run by the
+    `combine_batch` kernel as a batch of one."""
     bitmaps = jnp.asarray(bitmaps, dtype=jnp.uint32)
     if impl == "ref":
         return intersect_ref(bitmaps)
-    return intersect_pallas(bitmaps, interpret=interpret_mode())
+    L = bitmaps.shape[0]
+    chain = [(OP_AND, L + s - 1 if s else 0, s + 1) for s in range(L - 1)]
+    out, counts = combine_batch_pallas(
+        bitmaps[None], jnp.asarray(pack_programs([chain], L)),
+        interpret=interpret_mode())
+    return out[0], counts[0]
 
 
 def pack_programs(programs: list[list[tuple[int, int, int]]],
@@ -171,44 +176,36 @@ def unit_words(n_docs: int) -> int:
     return shape_class((n_docs + 31) // 32, W_CLASS_MIN)
 
 
-def launch_class(Q: int, L: int, W: int, S: int | None = None, *,
+def launch_class(Q: int, L: int, W: int, S: int, *,
                  n_docs: int) -> dict[str, int]:
     """The shape a launch of a batch of Q queries, L layers, W words and
     S program steps compiles at: Q a power of two; L and S powers of two
     of at least `LS_CLASS_MIN`; W the class of the unit's `n_docs`
     documents, or of W itself should a batch be wider than its unit.
-    Returns `Qc`, `Lc`, `Wc` and, for a program batch, `Sc`."""
-    out = dict(Qc=shape_class(Q), Lc=shape_class(L, LS_CLASS_MIN),
-               Wc=max(unit_words(n_docs), shape_class(W, W_CLASS_MIN)))
-    if S is not None:
-        out["Sc"] = shape_class(S, LS_CLASS_MIN)
-    return out
+    Returns `Qc`, `Lc`, `Wc` and `Sc`."""
+    return dict(Qc=shape_class(Q), Lc=shape_class(L, LS_CLASS_MIN),
+                Wc=max(unit_words(n_docs), shape_class(W, W_CLASS_MIN)),
+                Sc=shape_class(S, LS_CLASS_MIN))
 
 
-def pad_to_shape_class(bitmaps: np.ndarray, programs: np.ndarray | None,
-                       n_docs: int,
-                       ) -> tuple[np.ndarray, np.ndarray | None]:
+def pad_to_shape_class(bitmaps: np.ndarray, programs: np.ndarray,
+                       n_docs: int) -> tuple[np.ndarray, np.ndarray]:
     """Pad a batch to its `launch_class`, so that a stream of batches of
     many shapes runs a few compiled kernels, not one per shape.
 
     bitmaps (..., Q, L, W) uint32 and programs (..., Q, S, 3) int32, as
-    `combine_batch` and `combine_cluster` take them; `programs=None` is
-    an `intersect_batch` batch, whose added layers are all-ones (the AND
-    identity). Otherwise added queries, layers and words are zero, added
-    steps chain the last result through (AND(r, r)), and step slots move
-    up past the added layers. The result's first Q rows, cut to W words,
-    and their counts are the unpadded batch's.
+    `combine_batch` and `combine_cluster` take them. Added queries,
+    layers and words are zero, added steps chain the last result through
+    (AND(r, r)), and step slots move up past the added layers. The
+    result's first Q rows, cut to W words, and their counts are the
+    unpadded batch's.
     """
     *lead, Q, L, W = bitmaps.shape
-    S = None if programs is None else np.shape(programs)[-2]
+    S = np.shape(programs)[-2]
     c = launch_class(Q, L, W, S, n_docs=n_docs)
-    Qc, Lc = c["Qc"], c["Lc"]
+    Qc, Lc, Sc = c["Qc"], c["Lc"], c["Sc"]
     x = np.zeros((*lead, Qc, Lc, c["Wc"]), np.uint32)
     x[..., :Q, :L, :W] = bitmaps
-    if programs is None:
-        x[..., :Q, L:, :] = 0xFFFFFFFF
-        return x, None
-    Sc = c["Sc"]
     chain = Lc + np.arange(Sc) - 1
     chain[0] = 0                       # an added query: AND(layer 0, 0)
     p = np.empty((*lead, Qc, Sc, 3), np.int32)
@@ -230,20 +227,9 @@ def _launch(kernel, bitmaps, programs, n_docs: int,
     bitmaps = np.asarray(bitmaps, dtype=np.uint32)
     *_lead, Q, _L, W = bitmaps.shape
     x, p = pad_to_shape_class(bitmaps, programs, n_docs)
-    out, counts = kernel(*(x,) if p is None else (x, p),
-                         interpret=interpret)
+    out, counts = kernel(x, p, interpret=interpret)
     return (np.ascontiguousarray(np.asarray(out)[..., :Q, :W]),
             np.asarray(counts)[..., :Q])
-
-
-def intersect_batch(bitmaps, impl: str = "pallas", *, n_docs: int):
-    """(Q, L, W) uint32 → (bitmaps (Q, W), counts (Q,)). impl: pallas | ref.
-
-    `n_docs` is the document count of the index unit the batch's keys
-    come from, which fixes the launch's width class (`launch_class`)."""
-    if impl == "ref":
-        return intersect_batch_ref(jnp.asarray(bitmaps, dtype=jnp.uint32))
-    return _launch(intersect_batch_pallas, bitmaps, None, n_docs)
 
 
 def combine_batch(bitmaps, programs, impl: str = "pallas", *,
@@ -252,7 +238,9 @@ def combine_batch(bitmaps, programs, impl: str = "pallas", *,
 
     bitmaps: (Q, L, W) uint32; programs: (Q, S, 3) int32 (see
     `pack_programs`) → (result bitmaps (Q, W), counts (Q,)).
-    impl: pallas | ref. `n_docs` as for `intersect_batch`.
+    impl: pallas | ref. `n_docs` is the document count of the index unit
+    the batch's keys come from, which fixes the launch's width class
+    (`launch_class`).
     """
     if impl == "ref":
         return combine_batch_ref(jnp.asarray(bitmaps, dtype=jnp.uint32),
@@ -279,12 +267,12 @@ def combine_cluster(bitmaps, programs, impl: str = "pallas", *,
 
 def warm_shape_classes(n_docs: int, q_max: int, groups: int = 0,
                        ) -> list[tuple[int, ...]]:
-    """Launch each kernel once on zeros at every Q class up to `q_max`,
-    at the L, S and W classes of a unit of `n_docs` documents, so that
-    the jit cache holds every program a batch of up to `q_max` jobs over
-    that unit asks for. `groups` > 0 warms the cluster-fused kernel over
-    that many shard units (`n_docs` the largest unit's) in place of the
-    per-unit kernels. Returns each launch's (G, Q) or (Q,) shape."""
+    """Launch `combine_batch` once on zeros at every Q class up to
+    `q_max`, at the L, S and W classes of a unit of `n_docs` documents,
+    so that the jit cache holds every program a batch of up to `q_max`
+    jobs over that unit asks for. `groups` > 0 warms `combine_cluster`
+    over that many shard units (`n_docs` the largest unit's) in its
+    place. Returns each launch's (G, Q) or (Q,) shape."""
     launched = []
     for Q in (1 << i for i in range(shape_class(q_max).bit_length())):
         if groups:
@@ -292,9 +280,8 @@ def warm_shape_classes(n_docs: int, q_max: int, groups: int = 0,
                             np.zeros((groups, Q, 1, 3), np.int32),
                             n_docs=n_docs)
             launched.append((groups, Q))
-            continue
-        intersect_batch(np.zeros((Q, 1, 1), np.uint32), n_docs=n_docs)
-        combine_batch(np.zeros((Q, 1, 1), np.uint32),
-                      np.zeros((Q, 1, 3), np.int32), n_docs=n_docs)
-        launched.append((Q,))
+        else:
+            combine_batch(np.zeros((Q, 1, 1), np.uint32),
+                          np.zeros((Q, 1, 3), np.int32), n_docs=n_docs)
+            launched.append((Q,))
     return launched

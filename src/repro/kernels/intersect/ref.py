@@ -1,4 +1,4 @@
-"""Pure-jnp oracle for the IoU intersection kernel."""
+"""Pure-jnp oracles for the IoU combine kernel."""
 
 from __future__ import annotations
 
@@ -23,18 +23,6 @@ def intersect_ref(bitmaps: jnp.ndarray) -> tuple[jnp.ndarray, jnp.ndarray]:
     for l in range(1, bitmaps.shape[0]):
         out = jnp.bitwise_and(out, bitmaps[l])
     return out, jnp.sum(popcount(out), dtype=jnp.uint32)
-
-
-def intersect_batch_ref(bitmaps: jnp.ndarray,
-                        ) -> tuple[jnp.ndarray, jnp.ndarray]:
-    """Vectorized batch oracle. bitmaps: (Q, L, W) uint32 bitsets.
-
-    Returns (intersection bitmaps (Q, W), per-query counts (Q,)).
-    """
-    out = bitmaps[:, 0]
-    for l in range(1, bitmaps.shape[1]):
-        out = jnp.bitwise_and(out, bitmaps[:, l])
-    return out, jnp.sum(popcount(out), axis=1, dtype=jnp.uint32)
 
 
 def combine_batch_ref(bitmaps: jnp.ndarray, programs,

@@ -82,7 +82,7 @@ from ..index.lifecycle import (DEFAULT_GRACE_S, GCReport, Index,
                                open_many, publish_generation,
                                reachable_blobs, warn_ungraced_sweep)
 from ..index.planner import (DocContent, combine_cluster_planned,
-                             physical_plan, plan_batch, shard_quotas)
+                             plan_batch, shard_quotas)
 from ..index.query import Query, Regex
 from ..index.searcher import (BatchStats, QueryResult, QueryStats, Searcher,
                               _filter_unit_candidates, _merge_results,
@@ -1629,10 +1629,7 @@ class ClusterSearcher:
             for si, (units, jobs, outs_per_unit, _l, _e) in enumerate(legs):
                 for ui, unit in enumerate(units):
                     groups.append((si, unit))
-                    plans_by_group.append(
-                        [job.plan if job.plan is not None
-                         else physical_plan(job.lookup_q, ())
-                         for job in jobs])
+                    plans_by_group.append([job.plan for job in jobs])
                     words_by_group.append(outs_per_unit[ui])
                     common_by_group.append(
                         lambda w, u=unit: word_fingerprint(w) in u.common)
@@ -1865,14 +1862,10 @@ def _accept(job, ident: tuple, text: str,
     """Run one job's acceptance predicate on a fetched document, sharing
     the lazy `DocContent` (tokenization, word set) across every job that
     verifies the same document."""
-    if job.accept_text is not None:
-        return job.accept_text(text)
     content = content_cache.get(ident)
     if content is None:
         content = content_cache[ident] = DocContent(text)
-    if job.accept_doc is not None:
-        return job.accept_doc(content)
-    return job.accept_words(content.words)
+    return job.accepts(content)
 
 
 class _Canonical:

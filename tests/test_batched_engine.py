@@ -1,5 +1,5 @@
 """Batched query engine: cross-query fetch planning, range coalescing,
-superpost caching, and the batched Pallas intersection kernel.
+superpost caching, and AND chains on the batched Pallas combine kernel.
 
 The load-bearing invariant everywhere: batching/coalescing/caching may
 only change *when bytes move*, never *which bytes* a query sees — every
@@ -15,8 +15,8 @@ from repro.data import make_logs_like, write_corpus
 from repro.data.tokenizer import distinct_words
 from repro.index import (And, Builder, BuilderConfig, Or, Regex, Searcher,
                          Term, coalesce_requests, slice_payloads)
-from repro.kernels.intersect import (intersect, intersect_batch,
-                                     postings_to_bitmap,
+from repro.kernels.intersect import (OP_AND, combine_batch, intersect,
+                                     pack_programs, postings_to_bitmap,
                                      postings_to_bitmap_batch)
 from repro.serving import SearchService
 from repro.storage import (InMemoryBlobStore, LRUCache, RangeRequest,
@@ -243,7 +243,7 @@ def test_service_search_batch_uses_result_cache(engine):
     assert [r.texts for r in r1] == [r.texts for r in r2]
 
 
-# ----------------------------------------------- batched intersect kernel
+# ---------------------------------------- batched AND chains on the kernel
 def _random_ragged_batch(rng, Q, n_docs):
     batch = []
     for _ in range(Q):
@@ -254,13 +254,24 @@ def _random_ragged_batch(rng, Q, n_docs):
     return batch
 
 
+def _and_chains(batch):
+    """Each query's AND chain over its own layers, its steps numbered
+    past the batch's widest query (the kernel's slot layout)."""
+    L = max(len(posts) for posts in batch)
+    return pack_programs([[(OP_AND, L + s - 1 if s else 0, s + 1)
+                           for s in range(len(posts) - 1)]
+                          for posts in batch], L)
+
+
 @pytest.mark.parametrize("Q,n_docs", [(1, 100), (3, 4096), (5, 33_000)])
-def test_intersect_batch_matches_single_and_oracle(Q, n_docs):
+def test_combine_batch_and_chain_matches_single_and_oracle(Q, n_docs):
     rng = np.random.default_rng(Q * 7 + n_docs)
     batch = _random_ragged_batch(rng, Q, n_docs)
     bitmaps = postings_to_bitmap_batch(batch, n_docs)
-    out_p, cnt_p = intersect_batch(bitmaps, impl="pallas", n_docs=n_docs)
-    out_r, cnt_r = intersect_batch(bitmaps, impl="ref", n_docs=n_docs)
+    progs = _and_chains(batch)
+    out_p, cnt_p = combine_batch(bitmaps, progs, impl="pallas",
+                                 n_docs=n_docs)
+    out_r, cnt_r = combine_batch(bitmaps, progs, impl="ref", n_docs=n_docs)
     np.testing.assert_array_equal(np.asarray(out_p), np.asarray(out_r))
     np.testing.assert_array_equal(np.asarray(cnt_p), np.asarray(cnt_r))
     for q, posts in enumerate(batch):
@@ -274,12 +285,13 @@ def test_intersect_batch_matches_single_and_oracle(Q, n_docs):
 
 @settings(max_examples=10, deadline=None)
 @given(st.integers(0, 2**16))
-def test_intersect_batch_property_ragged(seed):
+def test_combine_batch_and_chain_property_ragged(seed):
     rng = np.random.default_rng(seed)
     n_docs = int(rng.integers(32, 2000))
     batch = _random_ragged_batch(rng, int(rng.integers(1, 6)), n_docs)
     bitmaps = postings_to_bitmap_batch(batch, n_docs)
-    out, counts = intersect_batch(bitmaps, impl="pallas", n_docs=n_docs)
+    out, counts = combine_batch(bitmaps, _and_chains(batch), impl="pallas",
+                                n_docs=n_docs)
     out = np.asarray(out)
     for q, posts in enumerate(batch):
         oracle = intersect_sorted(posts)

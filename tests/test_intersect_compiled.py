@@ -1,9 +1,10 @@
 """The intersect kernels on the chip's compiler, and which mode runs them.
 
-Part one compiles all four entry points for a described TPU v5e at the
+Part one compiles every entry point for a described TPU v5e at the
 widths a 1M-document deployment reaches (W = 31,250 words, padded to
 its class of 32,768 by the batch ops, Q = 64 queries, L = S = 16, G = 16
-shard units) — nothing runs, but Mosaic
+shard units; and the class a service warms for batches of up to 16
+jobs) — nothing runs, but Mosaic
 refuses here whatever it would refuse on the chip, so these tests guard
 the compiled path on every CPU run. The topology is described inside a
 module fixture, never at import, and compiles run with the persistent
@@ -30,15 +31,13 @@ from repro.kernels.intersect import (OP_AND, OP_ANDNOT, OP_OR,
                                      UnsupportedBackendError,
                                      combine_batch, combine_batch_ref,
                                      combine_cluster, combine_cluster_ref,
-                                     intersect, intersect_batch,
-                                     intersect_batch_ref, intersect_ref,
+                                     intersect, intersect_ref,
                                      interpret_mode, launch_class,
                                      pack_cluster_programs, pack_programs,
                                      postings_to_bitmap,
                                      postings_to_bitmap_batch)
 from repro.kernels.intersect.kernel import (combine_batch_pallas,
-                                            combine_cluster_pallas,
-                                            intersect_batch_pallas)
+                                            combine_cluster_pallas)
 from repro.kernels.intersect.ops import pad_to_shape_class, shape_class
 
 W_1M = (1_000_000 + 31) // 32          # words of a 1M-document universe
@@ -74,14 +73,17 @@ def no_compile_cache():
 def _shapes(kernel):
     """The op's entry point and operand shapes: the one-query op as given,
     each batch op's kernel at the class its wrapper pads a 1M-document
-    unit's batch to (`launch_class`)."""
+    unit's batch to (`launch_class`), and `combine_batch` at the class a
+    service warms for that unit's batches of up to 16 one-step jobs."""
     u32, i32 = jnp.uint32, jnp.int32
     c = launch_class(Q, L, W_1M, S, n_docs=1_000_000)
     Qc, Lc, Wc, Sc = c["Qc"], c["Lc"], c["Wc"], c["Sc"]
+    w = launch_class(16, 1, 1, 1, n_docs=1_000_000)
     return {
         "intersect": (intersect, [((L, W_1M), u32)]),
-        "intersect_batch": (_compiled(intersect_batch_pallas),
-                            [((Qc, Lc, Wc), u32)]),
+        "combine_batch_warm": (_compiled(combine_batch_pallas),
+                               [((w["Qc"], w["Lc"], w["Wc"]), u32),
+                                ((w["Qc"], w["Sc"], 3), i32)]),
         "combine_batch": (_compiled(combine_batch_pallas),
                           [((Qc, Lc, Wc), u32), ((Qc, Sc, 3), i32)]),
         "combine_cluster": (_compiled(combine_cluster_pallas),
@@ -93,7 +95,7 @@ def _compiled(entry):
     return functools.partial(entry, interpret=False)
 
 
-@pytest.mark.parametrize("kernel", ["intersect", "intersect_batch",
+@pytest.mark.parametrize("kernel", ["intersect", "combine_batch_warm",
                                     "combine_batch", "combine_cluster"])
 def test_kernel_compiles_for_v5e(kernel, v5e_chip, no_compile_cache,
                                  monkeypatch):
@@ -208,28 +210,29 @@ def test_intersect_vs_ref(L, n_docs):
     assert int(cnt_c) == int(cnt_r)
 
 
-@pytest.mark.parametrize("Q,L,n_docs", [(1, 2, 100), (5, 3, 33_000)])
-def test_intersect_batch_vs_ref(Q, L, n_docs):
-    rng = np.random.default_rng(Q * 7 + L)
+@pytest.mark.parametrize("program,Q,L,n_docs", [
+    ("random", 4, 3, 5000), ("random", 7, 4, 40_000),
+    ("and_chain", 1, 2, 100), ("and_chain", 5, 3, 33_000)])
+def test_combine_batch_vs_ref(program, Q, L, n_docs):
+    """Random programs, and the AND chain over every layer: the L-way
+    intersection, which must also equal `intersect_ref`'s per query."""
+    rng = np.random.default_rng(Q * 13 + L if program == "random"
+                                else Q * 7 + L)
     bm = postings_to_bitmap_batch(
         [_random_postings(rng, L, n_docs) for _ in range(Q)], n_docs)
-    out_c, cnt_c = intersect_batch(bm, impl="pallas", n_docs=n_docs)
-    out_r, cnt_r = intersect_batch_ref(bm)
-    assert (np.asarray(out_c) == np.asarray(out_r)).all()
-    assert (np.asarray(cnt_c) == np.asarray(cnt_r)).all()
-
-
-@pytest.mark.parametrize("Q,L,n_docs", [(4, 3, 5000), (7, 4, 40_000)])
-def test_combine_batch_vs_ref(Q, L, n_docs):
-    rng = np.random.default_rng(Q * 13 + L)
-    bm = postings_to_bitmap_batch(
-        [_random_postings(rng, L, n_docs) for _ in range(Q)], n_docs)
-    packed = pack_programs(_random_programs(rng, Q, L), L)
+    programs = _random_programs(rng, Q, L) if program == "random" else \
+        [[(OP_AND, L + s - 1 if s else 0, s + 1) for s in range(L - 1)]] * Q
+    packed = pack_programs(programs, L)
     out_c, cnt_c = combine_batch(bm, packed, impl="pallas",
                                  n_docs=n_docs)
     out_r, cnt_r = combine_batch_ref(bm, packed)
     assert (np.asarray(out_c) == np.asarray(out_r)).all()
     assert (np.asarray(cnt_c) == np.asarray(cnt_r)).all()
+    if program == "and_chain":
+        for q in range(Q):
+            want, want_cnt = intersect_ref(jnp.asarray(bm[q]))
+            assert (np.asarray(out_c[q]) == np.asarray(want)).all()
+            assert int(cnt_c[q]) == int(want_cnt)
 
 
 @pytest.mark.parametrize("G,Q,L,n_docs", [(3, 4, 3, 5000), (8, 2, 2, 2048)])

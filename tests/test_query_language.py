@@ -25,7 +25,7 @@ from repro.index import (And, Builder, BuilderConfig, Index, Not, Or,
                          Regex, Searcher, Term, normalize, parse,
                          physical_plan, query_words, to_string)
 from repro.index.builder import NGRAM_PREFIX
-from repro.index.planner import make_job, plan_batch
+from repro.index.planner import DocContent, make_job, matches, plan_batch
 from repro.kernels.intersect import (OP_AND, OP_ANDNOT, OP_OR,
                                      bitmap_to_docs, combine_batch,
                                      pack_programs, postings_to_bitmap_batch)
@@ -244,14 +244,28 @@ def test_lookup_set_skips_unbounded_or_branch():
 
 
 def test_classic_shapes_compile_to_classic_jobs():
-    for q in (Term("a"), And((Term("a"), Term("b"))),
-              Or((And((Term("a"), Term("b"))), Term("c")))):
+    """Every shape is one kind of job: a plan, the lookup of its words
+    and `matches` over the document as its one predicate. A Term/And/Or
+    tree looks up `query_words` of the tree, in its order, and a
+    standalone regex the n-grams of its prefilter."""
+    a, b, c = Term("a"), Term("b"), Term("c")
+    classic = (a, And((a, b)), Or((And((a, b)), c)),
+               Or((c, And((b, Or((a, c)))))))
+    regex = Regex("blk_[0-9]+")
+    docs = [DocContent("a b info"), DocContent("c blk_12 z"),
+            DocContent("a z")]
+    for q in classic + (regex, And((a, Not(b)))):
         job = make_job(q)
-        assert job.plan is None and job.accept_words is not None
-    rjob = make_job(Regex("blk_[0-9]+"))
-    assert rjob.plan is None and rjob.accept_text is not None
-    njob = make_job(And((Term("a"), Not(Term("b")))))
-    assert njob.plan is not None and njob.accept_doc is not None
+        tree = normalize(q)
+        assert job.plan.tree == tree
+        assert query_words(job.lookup_q) == job.plan.lookup_words
+        assert [job.accepts(d) for d in docs] == \
+            [matches(tree, d) for d in docs]
+    for q in classic:
+        assert make_job(q).plan.lookup_words == query_words(normalize(q))
+    assert make_job(regex).plan.lookup_words == query_words(regex)
+    with pytest.raises(ValueError, match="no literal run"):
+        make_job(Regex("[0-9]+"))
 
 
 # ------------------------------------------------- the brute-force oracle
@@ -462,8 +476,9 @@ def test_common_word_negation_prunes_candidates():
 
 
 def test_plan_batch_mixed_with_classic_byte_path():
-    """A batch mixing classic and planned shapes: classic members keep
-    plan=None (the byte-identical path) and all members stay exact."""
+    """A batch mixing Term/And/Or, regex and negation/phrase shapes:
+    every member is planned, Term/And/Or members look up `query_words`
+    of their tree, and all members stay exact under both combines."""
     store = InMemoryBlobStore()
     docs = make_logs_like(800, seed=17)
     corpus = write_corpus(store, "corpus/mx", docs, n_blobs=2)
@@ -475,9 +490,12 @@ def test_plan_batch_mixed_with_classic_byte_path():
                And((Term("info"), Not(Term("block")))),
                Phrase(("received", "block"))]
     jobs = plan_batch(queries, units=(s,))
-    assert [j.plan is None for j in jobs] == [True, True, True, False, False]
-    for q, r in zip(queries, s.query_batch(queries)):
-        assert set(r.texts) == _oracle(normalize(q), docs), to_string(q)
+    assert all(j.plan is not None for j in jobs)
+    for q, j in zip(queries[:2], jobs):
+        assert j.plan.lookup_words == query_words(normalize(q))
+    for impl in ("sorted", "bitmap"):
+        for q, r in zip(queries, s.query_batch(queries, impl=impl)):
+            assert set(r.texts) == _oracle(normalize(q), docs), to_string(q)
 
 
 # =================================================================== kernel

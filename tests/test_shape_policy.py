@@ -1,7 +1,7 @@
 """The kernel wrappers' shape policy (`kernels/intersect/ops.py`).
 
-`intersect_batch`, `combine_batch` and `combine_cluster` pad every batch
-to its launch class — Q a power of two, L and S powers of two of at
+`combine_batch` and `combine_cluster` pad every batch to its launch
+class — Q a power of two, L and S powers of two of at
 least 4, W the class of the index unit's document count — and cut the
 result back to the batch's own rows and words. Here: each wrapper's
 padded result equals the unpadded reference; a stream of varied served
@@ -17,8 +17,8 @@ from repro.data import make_logs_like, write_corpus
 from repro.index import BuilderConfig, Index
 from repro.kernels.intersect import (OP_AND, OP_ANDNOT, OP_OR,
                                      combine_batch, combine_cluster,
-                                     intersect_batch, launch_class,
-                                     unit_words, warm_shape_classes)
+                                     launch_class, unit_words,
+                                     warm_shape_classes)
 from repro.kernels.intersect import ops
 from repro.serving import (Frontend, FrontendConfig, SearchService,
                            ShardedIndex)
@@ -54,32 +54,34 @@ CASES = [(1, 1, 1, 3, 2000), (3, 2, 3, 63, 2000), (5, 3, 2, 1250, 40_000),
          (2, 1, 5, 1024, 32_768), (6, 5, 6, 40, 1280), (9, 4, 4, 700, 100)]
 
 
-@pytest.mark.parametrize("Q,L,S,W,n_docs", CASES)
-def test_intersect_batch_padded_equals_ref(Q, L, S, W, n_docs):
-    """Padded with all-ones layers, the AND identity: at L = 1 a
-    zero-padded layer would empty every result."""
-    rng = np.random.default_rng(Q * 100 + L * 10 + S)
-    bm = _bitmaps(rng, (Q, L), W)
-    out, cnt = intersect_batch(bm, n_docs=n_docs)
-    want, want_cnt = intersect_batch(bm, impl="ref", n_docs=n_docs)
-    assert out.shape == (Q, W) and cnt.shape == (Q,)
-    np.testing.assert_array_equal(out, np.asarray(want))
-    np.testing.assert_array_equal(cnt, np.asarray(want_cnt))
-    if L == 1:
-        np.testing.assert_array_equal(out, bm[:, 0])
+def _and_chains(n, L, S):
+    """n AND chains of exactly S steps over L layers: every layer ANDed
+    in (S >= L - 1 in every case), the rest ANDing layers in again."""
+    chain = [(OP_AND, L + s - 1 if s else 0, (s + 1) % L) for s in range(S)]
+    return np.array([chain] * n, np.int32)
 
 
+@pytest.mark.parametrize("program", ["random", "and_chain"])
 @pytest.mark.parametrize("Q,L,S,W,n_docs", CASES)
-def test_combine_batch_padded_equals_ref(Q, L, S, W, n_docs):
-    rng = np.random.default_rng(Q * 100 + L * 10 + S + 1)
-    bm = _bitmaps(rng, (Q, L), W)
-    progs = _programs(rng, Q, L, S)
+def test_combine_batch_padded_equals_ref(Q, L, S, W, n_docs, program):
+    """Padded with zero layers, which no program reads: an AND chain is
+    the L-way intersection, and at L = 1 its one layer."""
+    if program == "random":
+        rng = np.random.default_rng(Q * 100 + L * 10 + S + 1)
+        bm = _bitmaps(rng, (Q, L), W)
+        progs = _programs(rng, Q, L, S)
+    else:
+        rng = np.random.default_rng(Q * 100 + L * 10 + S)
+        bm = _bitmaps(rng, (Q, L), W)
+        progs = _and_chains(Q, L, S)
     out, cnt = combine_batch(bm, progs, n_docs=n_docs)
     want, want_cnt = combine_batch(bm, progs, impl="ref",
                                    n_docs=n_docs)
     assert out.shape == (Q, W) and cnt.shape == (Q,)
     np.testing.assert_array_equal(out, np.asarray(want))
     np.testing.assert_array_equal(cnt, np.asarray(want_cnt))
+    if program == "and_chain":
+        np.testing.assert_array_equal(out, np.bitwise_and.reduce(bm, axis=1))
 
 
 @pytest.mark.parametrize("Q,L,S,W,n_docs", CASES)
@@ -112,8 +114,7 @@ def test_unit_width_class(n_docs, words):
 def _record(monkeypatch):
     """Record the operand shapes at the kernels' entry points."""
     seen = []
-    for name in ("intersect_batch_pallas", "combine_batch_pallas",
-                 "combine_cluster_pallas"):
+    for name in ("combine_batch_pallas", "combine_cluster_pallas"):
         real = getattr(ops, name)
 
         def entry(*args, _real=real, _name=name, **kw):
@@ -140,10 +141,11 @@ POOL = ["info block", "task stage", "in stage executor task",
 
 
 def test_served_stream_reaches_only_the_q_classes(small_index, monkeypatch):
-    """About 50 batches of 1 to 16 AND and planned queries over one
-    small Index on the bitmap path launch at most (Q classes × kernels)
-    distinct shapes, all at the unit's L, S and W classes, and answer
-    as the NumPy path does."""
+    """50 batches of 1 to 16 queries over one small Index on the bitmap
+    path, each holding a query of more than one leaf, launch
+    `combine_batch` once a batch at most at one shape per Q class, all
+    at the unit's L, S and W classes, and answer as the NumPy path
+    does."""
     store, _corpus = small_index
     svc = SearchService(Index.open(store, "index/p"))
     rng = np.random.default_rng(4)
@@ -157,8 +159,8 @@ def test_served_stream_reaches_only_the_q_classes(small_index, monkeypatch):
     svc.close()
     shapes = set(seen)
     kernels = {name for name, _s in shapes}
-    assert kernels == {"intersect_batch_pallas", "combine_batch_pallas"}
-    assert len(seen) >= 50
+    assert kernels == {"combine_batch_pallas"}
+    assert len(seen) == 50         # one launch a batch
     assert len(shapes) <= len(Q_CLASSES) * len(kernels)
     words = unit_words(svc.searcher.n_docs)
     for _name, (bm, *prog) in shapes:
@@ -173,9 +175,8 @@ def test_warm_step_runs_exactly_the_q_classes(small_index, monkeypatch):
     words = unit_words(n_docs)
     seen = _record(monkeypatch)
     assert warm_shape_classes(n_docs, 16) == [(q,) for q in Q_CLASSES]
-    assert seen == [entry for q in Q_CLASSES for entry in (
-        ("intersect_batch_pallas", ((q, 4, words),)),
-        ("combine_batch_pallas", ((q, 4, words), (q, 4, 3))))]
+    assert seen == [("combine_batch_pallas", ((q, 4, words), (q, 4, 3)))
+                    for q in Q_CLASSES]
     seen.clear()
     assert warm_shape_classes(n_docs, 4, groups=3) == [(3, 1), (3, 2),
                                                         (3, 4)]

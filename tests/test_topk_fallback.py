@@ -97,12 +97,7 @@ def exhaustive(units, q, top_k, impl, text):
     outs, _ = lookup_units(units, [job.lookup_q], units[0]._fetcher)
 
     def accepted(r):
-        doc = text[(r.blob, r.offset, r.length)]
-        if job.accept_text is not None:
-            return job.accept_text(doc)
-        if job.accept_doc is not None:
-            return job.accept_doc(DocContent(doc))
-        return job.accept_words(DocContent(doc).words)
+        return job.accepts(DocContent(text[(r.blob, r.offset, r.length)]))
 
     samples, wholes, remaining = [], [], 0
     for unit, out in zip(units, outs):
