@@ -43,35 +43,73 @@ def encode_varints(values: np.ndarray) -> bytes:
     return bytes(out)
 
 
-def decode_varints(data: bytes, count: int) -> tuple[np.ndarray, int]:
-    """Decode `count` LEB128 varints; returns (values, bytes_consumed).
+# LEB128 spends 7 bits a byte, so a u64 takes at most 10 bytes
+_MAX_BYTES = 10
+_CONT = np.uint8(0x80)
+_LOW = np.uint8(0x7F)
+_SEVEN = np.uint8(7)
+_COLS = np.arange(_MAX_BYTES)
+_SHIFTS = np.arange(0, 7 * _MAX_BYTES, 7, dtype=np.uint64)
 
-    Vectorized: value boundaries are the bytes with the continuation bit
-    clear; each byte contributes its low 7 bits shifted by 7 × its position
-    within the value, and `np.add.reduceat` sums the disjoint bit groups.
-    This is the hot path of every superpost decode on the read path.
+
+def _decode(buf: np.ndarray, n: int | None = None,
+            ) -> tuple[np.ndarray, np.ndarray]:
+    """The first `n` (None: every) LEB128 varints that end in the byte
+    array `buf`, as u64, and the offset of each one's last byte.
+
+    One scan finds every value's last byte (the continuation bit clear);
+    the gaps between them are the widths. Most values on the read path
+    are one or two bytes: those are computed for the whole array at
+    once in 16 bits, from the last byte and, at width two, the byte
+    before it. Only values of three bytes or more, a handful in a common
+    word's superpost, are gathered byte by byte into u64, exactly up to
+    the 10 bytes of a u64. This is the hot path of every superpost
+    decode on the read path.
     """
+    ends = np.flatnonzero(buf < _CONT)[:n]
+    if not len(ends):
+        return np.empty(0, dtype=np.uint64), ends
+    width = np.empty_like(ends)
+    width[0] = ends[0] + 1
+    np.subtract(ends[1:], ends[:-1], out=width[1:])
+    two = (width > 1).view(np.uint8)
+    values = ((np.take(buf, ends).astype(np.uint16) << two * _SEVEN)
+              | (np.take(buf, ends - 1) & _LOW) * two).astype(np.uint64)
+    wide = np.flatnonzero(width > 2)
+    if len(wide):
+        w = width[wide]
+        inside = _COLS < w[:, None]
+        at = np.where(inside, (ends[wide] - w + 1)[:, None] + _COLS, 0)
+        values[wide] = np.bitwise_or.reduce(
+            (np.take(buf, at) & _LOW * inside).astype(np.uint64) << _SHIFTS,
+            axis=1)
+    return values, ends
+
+
+def decode_varints(data: bytes, count: int) -> tuple[np.ndarray, int]:
+    """Decode `count` LEB128 varints; returns (u64 values, bytes_consumed).
+
+    Never looks past what `count` values could occupy."""
     count = int(count)
-    if count == 0:
-        return np.empty(0, dtype=np.uint64), 0
-    # a u64 varint is at most 10 bytes — never scan past what `count`
-    # values could possibly occupy (decode_superpost passes whole tails)
-    buf = np.frombuffer(data, dtype=np.uint8)[:count * 10]
-    ends = np.flatnonzero((buf & 0x80) == 0)
-    if len(ends) < count:
+    buf = np.frombuffer(data, dtype=np.uint8)[:count * _MAX_BYTES]
+    values, ends = _decode(buf, count)
+    if len(values) < count:
         raise ValueError(
-            f"truncated varint stream: {len(ends)} values, need {count}")
-    ends = ends[:count]
-    consumed = int(ends[-1]) + 1
-    buf = buf[:consumed]
-    starts = np.empty(count, dtype=np.int64)
-    starts[0] = 0
-    starts[1:] = ends[:-1] + 1
-    byte_pos = np.arange(consumed, dtype=np.int64) \
-        - np.repeat(starts, ends - starts + 1)
-    contrib = (buf & np.uint8(0x7F)).astype(np.uint64) \
-        << (np.uint64(7) * byte_pos.astype(np.uint64))
-    return np.add.reduceat(contrib, starts), consumed
+            f"truncated varint stream: {len(values)} values, need {count}")
+    return values, int(ends[-1]) + 1 if count else 0
+
+
+def _counted(data: bytes, rows: int) -> np.ndarray:
+    """A block that is a count c followed by `rows` runs of c varints,
+    decoded in one pass over its bytes: the (rows, c) u64 values. The
+    block is all of `data` on the read path (a range read returns just
+    the superpost); bytes after it are decoded and dropped."""
+    values, _ = _decode(np.frombuffer(data, dtype=np.uint8))
+    count = int(values[0]) if len(values) else 0
+    if len(values) < 1 + rows * count:
+        raise ValueError(f"truncated varint stream: {len(values)} values, "
+                         f"need {1 + rows * count}")
+    return values[1:1 + rows * count].reshape(rows, count)
 
 
 # ---------------------------------------------------------------- superposts
@@ -111,14 +149,11 @@ def encode_superpost(keys: np.ndarray, lengths: np.ndarray) -> bytes:
 
 
 def decode_superpost(data: bytes) -> tuple[np.ndarray, np.ndarray]:
-    """Returns (sorted u64 posting keys, u64 lengths)."""
-    view = memoryview(data)               # zero-copy section slicing
-    (count,), pos = decode_varints(view, 1)
-    count = int(count)
-    deltas, used = decode_varints(view[pos:], count)
-    pos += used
-    lengths, _ = decode_varints(view[pos:], count)
-    return np.cumsum(deltas).astype(np.uint64), lengths
+    """Returns (sorted u64 posting keys, u64 lengths): the count, the
+    deltas and the lengths in one pass over the superpost's bytes, the
+    keys the running sum of the deltas, taken in place."""
+    deltas, lengths = _counted(data, 2)
+    return np.cumsum(deltas, out=deltas), lengths
 
 
 # -------------------------------------------------------------------- header
@@ -154,12 +189,5 @@ def pack_pointers(ptrs: list[BinPointer]) -> bytes:
 
 
 def unpack_pointers(data: bytes) -> list[BinPointer]:
-    (count,), pos = decode_varints(data, 1)
-    count = int(count)
-    blocks, used = decode_varints(data[pos:], count)
-    pos += used
-    offs, used = decode_varints(data[pos:], count)
-    pos += used
-    lens, _ = decode_varints(data[pos:], count)
-    return [BinPointer(int(b), int(o), int(n))
-            for b, o, n in zip(blocks, offs, lens)]
+    blocks, offs, lens = _counted(data, 3).tolist()
+    return [BinPointer(b, o, n) for b, o, n in zip(blocks, offs, lens)]

@@ -535,7 +535,7 @@ def lookup_units(units: list[Searcher], queries: list[Query | str],
     with spans.span("searcher.decode") as s:
         outs_per_unit: list[list[dict[str, tuple[np.ndarray,
                                                  np.ndarray]]]] = []
-        n_candidates = 0
+        n_candidates = n_superposts = n_bytes = 0
         for plan, base in zip(plans, bases):
             decoded: dict[int, tuple[np.ndarray, np.ndarray]] = {}
             word_out: dict[str, tuple[np.ndarray, np.ndarray]] = {}
@@ -547,18 +547,27 @@ def lookup_units(units: list[Searcher], queries: list[Query | str],
                     if i not in decoded:
                         decoded[i] = codec.decode_superpost(
                             payloads[base + i])
+                        n_bytes += len(payloads[base + i])
                     posts.append(decoded[i])
+                if len(posts) == 1:
+                    # one layer (a common word, or a hedge dropped the
+                    # rest): its candidates are that superpost, shared
+                    # with `decoded`, which no consumer writes into
+                    word_out[w] = posts[0]
+                    continue
                 keys = intersect_sorted([k for k, _len in posts])
                 # recover lengths from whichever layer, via searchsorted
                 k0, l0 = posts[0]
                 lengths = l0[np.searchsorted(k0, keys)]
                 word_out[w] = (keys, lengths)
+            n_superposts += len(decoded)
             outs = [{w: word_out[w] for w in wl} for wl in word_lists]
             n_candidates += int(
                 sum(len(k) for d in outs for k, _ in d.values()))
             outs_per_unit.append(outs)
         stats.n_candidates = n_candidates
-        s.set(candidates=n_candidates)
+        s.set(candidates=n_candidates, superposts=n_superposts,
+              bytes=n_bytes)
     return outs_per_unit, stats
 
 

@@ -11,7 +11,7 @@ import pytest
 
 from repro.analysis import spans
 from repro.data import make_logs_like, write_corpus
-from repro.index import BuilderConfig, Index, searcher
+from repro.index import BuilderConfig, Index, parse, query_words, searcher
 from repro.serving import SearchService
 from repro.storage import InMemoryBlobStore
 
@@ -143,15 +143,35 @@ CHILDREN = {"searcher.plan", "searcher.round1", "searcher.decode",
 
 
 @pytest.fixture(scope="module")
-def service():
+def store():
     store = InMemoryBlobStore()
     docs = make_logs_like(3000, seed=3)
     corpus = write_corpus(store, "corpus/s", docs, n_blobs=2)
     Index.build(corpus, BuilderConfig(B=2000, F0=1.0), store,
                 "index/s").close()
+    return store
+
+
+@pytest.fixture(scope="module")
+def service(store):
     svc = SearchService(Index.open(store, "index/s"))
     yield svc
     svc.close()
+
+
+def test_decode_span_counts_the_superposts_and_their_bytes(store):
+    """`searcher.decode` carries the unique superposts it decoded and
+    their payload bytes: one per planned request, summed lengths."""
+    unit = Index.open(store, "index/s").searcher()
+    queries = [parse(q) for q in QUERIES]
+    plan = unit._plan_words([query_words(q) for q in queries])
+    spans.start()
+    searcher.lookup_units([unit], queries, unit._fetcher)
+    decode, = (s for s in spans.stop() if s.name == "searcher.decode")
+    payloads = [store.get_range(r) for r in plan.requests]
+    assert decode.attrs["superposts"] == len(set(plan.requests)) \
+        == len(payloads) > len(QUERIES)
+    assert decode.attrs["bytes"] == sum(len(p) for p in payloads) > 0
 
 
 def test_served_batch_span_tree_and_identical_results(service):

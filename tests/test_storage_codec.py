@@ -162,3 +162,104 @@ def test_superpost_compression_beats_raw():
     lengths = rng.integers(50, 300, size=1000).astype(np.uint64)
     blob = codec.encode_superpost(keys, lengths)
     assert len(blob) < 0.5 * 16 * 1000
+
+
+# ------------------------------------------- the decoders against LEB128
+def _leb128(data: bytes, count: int) -> tuple[list[int], int]:
+    """Plain LEB128, a byte at a time: the first `count` values of
+    `data` and the bytes they take."""
+    values, pos = [], 0
+    for _ in range(count):
+        value = shift = 0
+        while True:
+            if pos == len(data):
+                raise ValueError("truncated")
+            byte = data[pos]
+            pos += 1
+            value |= (byte & 0x7F) << shift
+            shift += 7
+            if byte < 0x80:
+                break
+        values.append(value)
+    return values, pos
+
+
+def _superpost_oracle(data: bytes) -> tuple[list[int], list[int]]:
+    """A superpost read with `_leb128`: its count, then the key deltas,
+    then the lengths; the keys are the running sum of the deltas."""
+    (count,), pos = _leb128(data, 1)
+    values, _ = _leb128(data[pos:], 2 * count)
+    keys, total = [], 0
+    for delta in values[:count]:
+        total += delta
+        keys.append(total)
+    return keys, values[count:]
+
+
+def _width(w: int) -> tuple[int, int]:
+    """The least and the largest value LEB128 writes in `w` bytes."""
+    return 1 << 7 * (w - 1), min((1 << 7 * w) - 1, 2**64 - 1)
+
+
+def _case(values, keys, lengths, tail=b"", truncate=False):
+    return (list(values), list(keys), list(lengths), tail, truncate)
+
+
+_RNG = np.random.default_rng(18)
+_BLOBS = np.sort(codec.posting_key(
+    np.repeat([0, 1, 2, 7, 300], 40),
+    np.concatenate([np.sort(_RNG.choice(1 << 40, 40, replace=False))
+                    for _ in range(5)])))
+DECODE_CASES = {
+    # a value of each width, mixed with one-byte values; keys whose
+    # deltas and lengths take that width
+    **{f"width-{w}": _case([_width(w)[0], _width(w)[1], 3, _width(w)[0]],
+                           [_width(w)[0], _width(w)[1]],
+                           [_width(w)[1], _width(w)[0]])
+       for w in range(1, 11)},
+    "u64-max": _case([2**64 - 1, 0, 2**64 - 1], [2**64 - 1], [2**64 - 1]),
+    # keys of several blobs: the delta across a blob is `blob_key << 40`
+    # wide
+    "across-blobs": _case(_BLOBS.tolist(), _BLOBS.tolist(),
+                          _RNG.integers(1, 400, len(_BLOBS)).tolist()),
+    "count-0": _case([], [], []),
+    "count-1": _case([300], [12345], [77]),
+    "one-byte": _case(range(128), range(128), range(127, -1, -1)),
+    "two-byte": _case(range(128, 16384, 97),
+                      np.cumsum(np.arange(128, 16384, 97)).tolist(),
+                      range(16383, 127, -97)),
+    "trailing-bytes": _case([5, 1 << 30, 6], [9, 1 << 33, (1 << 33) + 1],
+                            [4, 300, 2**40], tail=b"\x05\x81\x01\xff"),
+    "truncated": _case([1 << 20, 1 << 40], [3, 1 << 35], [1 << 20, 9],
+                       truncate=True),
+}
+
+
+@pytest.mark.parametrize("case", list(DECODE_CASES))
+def test_decoders_match_plain_leb128(case):
+    """`decode_varints` (values and bytes consumed) and
+    `decode_superpost` read what a byte-at-a-time LEB128 reads, as u64,
+    and raise `ValueError` on a stream cut short."""
+    values, keys, lengths, tail, truncate = DECODE_CASES[case]
+    stream = codec.encode_varints(np.asarray(values, dtype=np.uint64))
+    post = codec.encode_superpost(np.asarray(keys, dtype=np.uint64),
+                                  np.asarray(lengths, dtype=np.uint64))
+    if truncate:
+        with pytest.raises(ValueError):
+            codec.decode_varints(stream[:-1], len(values))
+        with pytest.raises(ValueError):
+            codec.decode_superpost(post[:-1])
+        return
+    want, used = _leb128(stream + tail, len(values))
+    got, consumed = codec.decode_varints(stream + tail, len(values))
+    assert got.dtype == np.uint64 and got.tolist() == want == values
+    assert consumed == used == len(stream)
+    # a prefix stops where its last value ends
+    k = len(values) // 2
+    got, consumed = codec.decode_varints(stream + tail, k)
+    assert (got.tolist(), consumed) == _leb128(stream, k)
+    want_keys, want_lengths = _superpost_oracle(post + tail)
+    got_keys, got_lengths = codec.decode_superpost(post + tail)
+    assert got_keys.dtype == got_lengths.dtype == np.uint64
+    assert got_keys.tolist() == want_keys == keys
+    assert got_lengths.tolist() == want_lengths == lengths
